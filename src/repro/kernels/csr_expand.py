@@ -10,8 +10,8 @@ TPU adaptation (DESIGN.md §2): like the edge-scan kernel, the per-edge
 scatter becomes a block one-hot matmul so the MXU does the segment gather.
 For edge block ``j`` and output row block ``i``,
 
-    onehot[e, v] = (start[v] <= e_global < end[v])          (VPU compare)
-    out[i]      += onehot^T @ values_j                      (MXU matmul)
+    onehot[v, e] = (start[v] <= e_global < end[v])          (VPU compare)
+    out[i]      += onehot @ values_j                        (MXU matmul)
 
 where ``start``/``end`` are the vertex block's indptr slices.  Because
 offsets are sorted, the block-skip test is **exact** rather than a Min-Max
@@ -33,11 +33,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import (block_stats, lane_block, mxu_precision, round_up,
+                                  row_block, stat_spec)
+
 DEFAULT_BLOCK_E = 1024   # edges per block  (8*128-aligned)
 DEFAULT_BLOCK_N = 512    # output rows per block
 
 
-def _kernel(blk_lo_ref, blk_hi_ref, starts_ref, ends_ref, val_ref, out_ref,
+def _kernel(stats_ref, starts_ref, ends_ref, val_ref, out_ref,
             *, block_e: int, block_n: int):
     j = pl.program_id(1)
 
@@ -47,20 +50,19 @@ def _kernel(blk_lo_ref, blk_hi_ref, starts_ref, ends_ref, val_ref, out_ref,
 
     e_lo = j * block_e
     # exact range-overlap skip: sorted offsets make this provably lossless
-    overlaps = (blk_hi_ref[0] > e_lo) & (blk_lo_ref[0] < e_lo + block_e)
+    overlaps = (stats_ref[0, 0, 1] > e_lo) & (stats_ref[0, 0, 0] < e_lo + block_e)
 
     @pl.when(overlaps)
     def _accumulate():
-        starts = starts_ref[...]                              # (block_n,)
-        ends = ends_ref[...]                                  # (block_n,)
-        eidx = jax.lax.broadcasted_iota(jnp.int32, (block_e, block_n), 0) + e_lo
-        onehot = ((eidx >= starts[None, :]) & (eidx < ends[None, :])).astype(
+        starts = starts_ref[...]                              # (block_n, 1)
+        ends = ends_ref[...]                                  # (block_n, 1)
+        eidx = jax.lax.broadcasted_iota(jnp.int32, (block_n, block_e), 1) + e_lo
+        onehot = ((eidx >= starts) & (eidx < ends)).astype(
             val_ref.dtype
-        )                                                     # (block_e, block_n)
-        out_ref[...] += jax.lax.dot_general(
-            onehot, val_ref[...],
-            dimension_numbers=(((0,), (0,)), ((), ())),       # onehot^T @ values
-            preferred_element_type=jnp.float32,
+        )                                                     # (block_n, block_e)
+        out_ref[...] += jnp.dot(
+            onehot, val_ref[...], preferred_element_type=jnp.float32,
+            precision=mxu_precision(val_ref.dtype),
         ).astype(out_ref.dtype)
 
 
@@ -83,10 +85,10 @@ def csr_segment_sum_pallas(
     """
     e, d = values.shape
     n = num_segments
-    block_e = min(block_e, max(8, e))
-    block_n = min(block_n, max(8, n))
-    e_pad = -(-max(e, 1) // block_e) * block_e
-    n_pad = -(-max(n, 1) // block_n) * block_n
+    block_e = lane_block(block_e, e)
+    block_n = row_block(block_n, n)
+    e_pad = round_up(max(e, 1), block_e)
+    n_pad = round_up(max(n, 1), block_n)
     if e_pad != e:
         values = jnp.pad(values, ((0, e_pad - e), (0, 0)))
 
@@ -104,21 +106,19 @@ def csr_segment_sum_pallas(
     # [starts[first], ends[last]) — block min/max without a reduction scan
     starts_blocks = starts.reshape(n_nblk, block_n)
     ends_blocks = ends.reshape(n_nblk, block_n)
-    blk_lo = starts_blocks[:, 0].astype(jnp.int32)
-    blk_hi = ends_blocks[:, -1].astype(jnp.int32)
+    stats = block_stats(starts_blocks[:, 0], ends_blocks[:, -1])
 
     out = pl.pallas_call(
         functools.partial(_kernel, block_e=block_e, block_n=block_n),
         grid=(n_nblk, n_eblk),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),            # blk_lo
-            pl.BlockSpec((1,), lambda i, j: (i,)),            # blk_hi
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),      # range starts
-            pl.BlockSpec((block_n,), lambda i, j: (i,)),      # range ends
-            pl.BlockSpec((block_e, d), lambda i, j: (j, 0)),  # edge values
+            stat_spec(lambda i, j: i),                          # edge range
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),    # range starts
+            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),    # range ends
+            pl.BlockSpec((block_e, d), lambda i, j: (j, 0)),    # edge values
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d), jnp.float32),
         interpret=interpret,
-    )(blk_lo, blk_hi, starts, ends, values)
+    )(stats, starts.reshape(n_pad, 1), ends.reshape(n_pad, 1), values)
     return out[:n].astype(values.dtype)

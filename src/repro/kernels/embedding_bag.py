@@ -25,11 +25,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import block_stats, mxu_precision, round_up, row_block, stat_spec
+
 DEFAULT_BLOCK_B = 256
 DEFAULT_BLOCK_V = 512
 
 
-def _kernel(blk_min_ref, blk_max_ref, idx_ref, w_ref, table_ref, out_ref, *, block_v: int):
+def _kernel(stats_ref, idx_ref, w_ref, table_ref, out_ref, *, block_v: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -37,25 +39,22 @@ def _kernel(blk_min_ref, blk_max_ref, idx_ref, w_ref, table_ref, out_ref, *, blo
         out_ref[...] = jnp.zeros_like(out_ref)
 
     v_lo = j * block_v
-    overlaps = (blk_max_ref[0] >= v_lo) & (blk_min_ref[0] < v_lo + block_v)
+    overlaps = (stats_ref[0, 0, 1] >= v_lo) & (stats_ref[0, 0, 0] < v_lo + block_v)
 
     @pl.when(overlaps)
     def _accumulate():
-        idx = idx_ref[...]            # (block_b, L)
-        w = w_ref[...]                # (block_b, L)
-        block_b, bag = idx.shape
-
-        def body(l, m):
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_b, block_v), 1) + v_lo
-            hit = (idx[:, l][:, None] == cols).astype(w.dtype)
-            return m + hit * w[:, l][:, None]
-
-        m0 = jnp.zeros((block_b, block_v), dtype=jnp.float32)
-        m = jax.lax.fori_loop(0, bag, body, m0)   # (block_b, block_v)
-        out_ref[...] += jax.lax.dot_general(
+        block_b, bag = idx_ref.shape
+        cols = jax.lax.broadcasted_iota(jnp.int32, (block_b, block_v), 1) + v_lo
+        m = jnp.zeros((block_b, block_v), dtype=jnp.float32)
+        # the bag length is static and small: one static (block_b, 1) column
+        # per slot, compared against the vocab block's lane iota
+        for l in range(bag):
+            hit = (idx_ref[:, l:l + 1] == cols).astype(jnp.float32)
+            m = m + hit * w_ref[:, l:l + 1]
+        out_ref[...] += jnp.dot(
             m, table_ref[...].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
+            precision=mxu_precision(jnp.float32),
         ).astype(out_ref.dtype)
 
 
@@ -77,10 +76,10 @@ def embedding_bag_pallas(
     """
     v, d = table.shape
     b, bag = indices.shape
-    block_b = min(block_b, max(8, b))
-    block_v = min(block_v, max(8, v))
-    b_pad = -(-b // block_b) * block_b
-    v_pad = -(-v // block_v) * block_v
+    block_b = row_block(block_b, b)
+    block_v = row_block(block_v, v)
+    b_pad = round_up(b, block_b)
+    v_pad = round_up(v, block_v)
     if b_pad != b:
         indices = jnp.pad(indices, ((0, b_pad - b), (0, 0)), constant_values=-1)
         weights = jnp.pad(weights, ((0, b_pad - b), (0, 0)))
@@ -92,15 +91,14 @@ def embedding_bag_pallas(
     n_vblk = v_pad // block_v
     idx_blocks = indices.reshape(n_bblk, block_b * bag)
     live = (weights.reshape(n_bblk, block_b * bag) != 0) & (idx_blocks >= 0)
-    blk_min = jnp.where(live, idx_blocks, v_pad).min(axis=1).astype(jnp.int32)
-    blk_max = jnp.where(live, idx_blocks, -1).max(axis=1).astype(jnp.int32)
+    stats = block_stats(jnp.where(live, idx_blocks, v_pad).min(axis=1),
+                        jnp.where(live, idx_blocks, -1).max(axis=1))
 
     out = pl.pallas_call(
         functools.partial(_kernel, block_v=block_v),
         grid=(n_bblk, n_vblk),
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),                # blk_min
-            pl.BlockSpec((1,), lambda i, j: (i,)),                # blk_max
+            stat_spec(lambda i, j: i),                            # index min/max
             pl.BlockSpec((block_b, bag), lambda i, j: (i, 0)),    # indices
             pl.BlockSpec((block_b, bag), lambda i, j: (i, 0)),    # weights
             pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),      # table tile
@@ -108,5 +106,5 @@ def embedding_bag_pallas(
         out_specs=pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, d), jnp.float32),
         interpret=interpret,
-    )(blk_min, blk_max, indices, weights.astype(jnp.float32), table)
+    )(stats, indices, weights.astype(jnp.float32), table)
     return out[:b].astype(table.dtype)

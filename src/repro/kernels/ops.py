@@ -2,13 +2,19 @@
 
 Every op has a pure-jnp reference path (``ref.py``) — used on CPU/GPU and for
 the 512-device SPMD dry-run — and a Pallas TPU kernel selected when running
-on TPU (or when forced for testing).  The dispatch contract:
+on TPU.  The dispatch contract:
 
-    backend == tpu  and shapes suitable  -> Pallas kernel
-    REPRO_PALLAS=interpret                -> Pallas kernel in interpret mode
+    backend == tpu                        -> Pallas kernel (REPRO_PALLAS must
+                                            be unset: nothing moves a chip
+                                            run off its kernels)
+    REPRO_PALLAS=interpret (non-TPU only) -> Pallas kernel in interpret mode
                                             (CPU execution of the kernel body;
                                             how kernels are validated here)
     otherwise                             -> jnp reference
+
+Every kernel-backed op carries a ``custom_vjp`` whose backward pass is
+written in jnp, so trainers differentiate through the kernels on the chip
+exactly as they do through the references on the CPU.
 
 All ops are shape-polymorphic jit-stable functions safe to call inside
 pjit/shard_map-traced code.
@@ -16,6 +22,7 @@ pjit/shard_map-traced code.
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -30,13 +37,21 @@ from repro.kernels.flash_attention import flash_attention_pallas
 
 def _mode() -> str:
     forced = os.environ.get("REPRO_PALLAS", "").lower()
-    if forced in ("interpret", "force", "off"):
+    on_tpu = jax.default_backend() == "tpu"
+    if forced not in ("", "interpret", "off"):
+        raise ValueError(f"REPRO_PALLAS={forced!r}: expected 'interpret' or 'off'")
+    if forced and on_tpu:
+        raise RuntimeError(
+            f"REPRO_PALLAS={forced} on a TPU backend would move the kernels off "
+            f"the chip; unset it (it selects interpret/reference mode for CPU "
+            f"validation only)")
+    if forced:
         return forced
-    return "tpu" if jax.default_backend() == "tpu" else "off"
+    return "tpu" if on_tpu else "off"
 
 
 def use_pallas() -> bool:
-    return _mode() in ("tpu", "force", "interpret")
+    return _mode() in ("tpu", "interpret")
 
 
 def _interpret() -> bool:
@@ -69,9 +84,7 @@ class attention_unroll:
 def segment_sum(values: jax.Array, segment_ids: jax.Array, num_segments: int) -> jax.Array:
     """1-D or 2-D segment sum. Dispatches the 2-D case to the Pallas kernel."""
     if values.ndim == 2 and use_pallas():
-        return edge_segment_sum_pallas(
-            values, segment_ids, num_segments, interpret=_interpret()
-        )
+        return edge_segment_sum(values, segment_ids, num_segments)
     return jax.ops.segment_sum(values, segment_ids, num_segments=num_segments)
 
 
@@ -91,16 +104,60 @@ def segment_mean(values: jax.Array, segment_ids: jax.Array, num_segments: int) -
     return total / (denom[:, None] if values.ndim == 2 else denom)
 
 
+def _rows_at(g: jax.Array, seg: jax.Array, num_segments: int) -> jax.Array:
+    """``g[seg]`` with rows outside ``[0, num_segments)`` zeroed: the
+    cotangent of a segment sum, whose padding/out-of-range ids were dropped
+    in the forward pass."""
+    valid = (seg >= 0) & (seg < num_segments)
+    rows = g[jnp.clip(seg, 0, num_segments - 1)]
+    return jnp.where(valid[:, None], rows, jnp.zeros_like(rows))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _edge_segment_sum_kernel(values, dst, num_segments):
+    return edge_segment_sum_pallas(values, dst, num_segments, interpret=_interpret())
+
+
+def _edge_segment_sum_fwd(values, dst, num_segments):
+    return _edge_segment_sum_kernel(values, dst, num_segments), dst
+
+
+def _edge_segment_sum_bwd(num_segments, dst, g):
+    return _rows_at(g, dst, num_segments), None
+
+
+_edge_segment_sum_kernel.defvjp(_edge_segment_sum_fwd, _edge_segment_sum_bwd)
+
+
 def edge_segment_sum(values: jax.Array, dst: jax.Array, num_segments: int) -> jax.Array:
     """(E, D) edge values scattered-added to (N, D). The EdgeScan hot path."""
     if use_pallas():
-        return edge_segment_sum_pallas(values, dst, num_segments, interpret=_interpret())
+        return _edge_segment_sum_kernel(values, dst, num_segments)
     return _ref.edge_segment_sum(values, dst, num_segments)
 
 
 def masked_edge_segment_sum(values, src, dst, frontier, num_segments: int) -> jax.Array:
     mask = frontier[src].astype(values.dtype)
     return edge_segment_sum(values * mask[:, None], dst, num_segments)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _csr_segment_sum_kernel(values, indptr, num_segments):
+    return csr_segment_sum_pallas(values, indptr, num_segments, interpret=_interpret())
+
+
+def _csr_segment_sum_fwd(values, indptr, num_segments):
+    # edge e's segment is the range holding it; edges past indptr[N] own
+    # none (segment N, masked in the backward pass)
+    seg = jnp.searchsorted(indptr, jnp.arange(values.shape[0]), side="right") - 1
+    return _csr_segment_sum_kernel(values, indptr, num_segments), seg
+
+
+def _csr_segment_sum_bwd(num_segments, seg, g):
+    return _rows_at(g, seg, num_segments), None
+
+
+_csr_segment_sum_kernel.defvjp(_csr_segment_sum_fwd, _csr_segment_sum_bwd)
 
 
 def csr_segment_sum(values: jax.Array, indptr: jax.Array, num_segments: int) -> jax.Array:
@@ -111,12 +168,8 @@ def csr_segment_sum(values: jax.Array, indptr: jax.Array, num_segments: int) -> 
     Like ``segment_sum``, only the 2-D case dispatches to the Pallas
     one-hot-matmul kernel — a single value column would waste the MXU.
     """
-    if values.ndim == 1:
-        return _ref.csr_segment_sum(values, indptr, num_segments)
-    if use_pallas():
-        return csr_segment_sum_pallas(
-            values, indptr, num_segments, interpret=_interpret()
-        )
+    if values.ndim == 2 and use_pallas():
+        return _csr_segment_sum_kernel(values, indptr, num_segments)
     return _ref.csr_segment_sum(values, indptr, num_segments)
 
 
@@ -159,6 +212,32 @@ def tree_unstack(tree) -> list:
 # embedding bag
 # ---------------------------------------------------------------------------
 
+@jax.custom_vjp
+def _embedding_bag_kernel(table, indices, weights):
+    return embedding_bag_pallas(table, indices, weights, interpret=_interpret())
+
+
+def _embedding_bag_fwd(table, indices, weights):
+    return _embedding_bag_kernel(table, indices, weights), (table, indices, weights)
+
+
+def _embedding_bag_bwd(res, g):
+    """out[b] = sum_l w[b,l] table[idx[b,l]]; indices outside [0, V) match
+    no row in the kernel, so they get no table or weight gradient."""
+    table, indices, weights = res
+    v, d = table.shape
+    valid = (indices >= 0) & (indices < v)                       # (B, L)
+    rows = jnp.where(valid, indices, v).reshape(-1)              # v = dropped
+    per_slot = weights.astype(g.dtype)[..., None] * g[:, None, :]  # (B, L, D)
+    d_table = jax.ops.segment_sum(per_slot.reshape(-1, d), rows, num_segments=v)
+    gathered = table[jnp.clip(indices, 0, v - 1)]                # (B, L, D)
+    d_weights = jnp.where(valid, (gathered * g[:, None, :]).sum(-1), 0.0)
+    return d_table.astype(table.dtype), None, d_weights.astype(weights.dtype)
+
+
+_embedding_bag_kernel.defvjp(_embedding_bag_fwd, _embedding_bag_bwd)
+
+
 def embedding_bag(
     table: jax.Array,
     indices: jax.Array,
@@ -169,9 +248,7 @@ def embedding_bag(
     if weights is None:
         weights = jnp.ones(indices.shape, dtype=table.dtype)
     if use_pallas():
-        out = embedding_bag_pallas(
-            table, indices, weights, interpret=_interpret()
-        )
+        out = _embedding_bag_kernel(table, indices, weights)
         if mode == "mean":
             denom = jnp.maximum(weights.sum(axis=1, keepdims=True), 1e-9)
             out = out / denom.astype(out.dtype)
@@ -183,27 +260,56 @@ def embedding_bag(
 # attention
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_kernel(q, k, v, kv_len_mask, causal, block_q, block_kv):
+    return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
+                                  block_kv=block_kv, interpret=_interpret(),
+                                  kv_len_mask=kv_len_mask)
+
+
+def _flash_fwd(q, k, v, kv_len_mask, causal, block_q, block_kv):
+    out = _flash_kernel(q, k, v, kv_len_mask, causal, block_q, block_kv)
+    return out, (q, k, v, kv_len_mask)
+
+
+def _flash_bwd(causal, block_q, block_kv, res, g):
+    """Backward through the streaming-softmax jnp reference (same masking
+    and decode alignment as the kernel), recomputed from q, k, v."""
+    q, k, v, kv_len_mask = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: _ref.attention_blockwise(
+            q, k, v, causal=causal, block_kv=block_kv, kv_len_mask=kv_len_mask),
+        q, k, v)
+    return (*vjp(g), None)
+
+
+_flash_kernel.defvjp(_flash_fwd, _flash_bwd)
+
+
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
     block_q: int = 512, block_kv: int = 512, kv_len_mask=None,
 ) -> jax.Array:
     """Memory-safe attention. q,k,v: (B, H, S, Dh), H pre-expanded for GQA.
-    ``kv_len_mask``: optional traced scalar masking keys >= it."""
+    ``kv_len_mask``: optional traced scalar masking keys >= it.
+
+    With Pallas on, a sequence length that does not divide its block is an
+    error: the kernel never hands such a shape to the reference quietly."""
     q_len, kv_len = q.shape[2], k.shape[2]
-    if use_pallas() and q_len % min(block_q, q_len) == 0 and kv_len % min(block_kv, kv_len) == 0:
-        return flash_attention_pallas(
-            q, k, v, causal=causal,
-            block_q=min(block_q, q_len), block_kv=min(block_kv, kv_len),
-            interpret=_interpret(), kv_len_mask=kv_len_mask,
-        )
+    block_q, block_kv = min(block_q, q_len), min(block_kv, kv_len)
+    if use_pallas():
+        if q_len % block_q or kv_len % block_kv:
+            raise ValueError(
+                f"flash_attention: q_len={q_len} / kv_len={kv_len} do not divide "
+                f"blocks ({block_q}, {block_kv}); pad the sequence to a block "
+                f"multiple")
+        return _flash_kernel(q, k, v, kv_len_mask, causal, block_q, block_kv)
     from repro.perf_flags import enabled
     if (enabled("tri") and causal and kv_len_mask is None
-            and q_len == kv_len and q_len % min(block_kv, kv_len) == 0
-            and q_len // min(block_kv, kv_len) >= 2):
-        return _ref.attention_triangular(q, k, v, causal=True,
-                                         block=min(block_kv, kv_len),
+            and q_len == kv_len and q_len % block_kv == 0
+            and q_len // block_kv >= 2):
+        return _ref.attention_triangular(q, k, v, causal=True, block=block_kv,
                                          unroll=_ATTN_UNROLL)
-    return _ref.attention_blockwise(q, k, v, causal=causal,
-                                    block_kv=min(block_kv, kv_len),
+    return _ref.attention_blockwise(q, k, v, causal=causal, block_kv=block_kv,
                                     kv_len_mask=kv_len_mask,
                                     unroll=_ATTN_UNROLL)

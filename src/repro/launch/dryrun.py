@@ -1,18 +1,21 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input shape x mesh) combination:
 ``jax.jit(step, in_shardings, out_shardings).lower(specs).compile()`` must
 succeed on the production meshes (16x16 single-pod and 2x16x16 multi-pod,
-512 placeholder CPU devices).  The compiled artifact yields
+512 placeholder CPU devices, modeling ``MODELED_KIND`` chips).  The
+compiled artifact yields
 ``memory_analysis()`` (proves per-device fit) and ``cost_analysis()`` +
 SPMD HLO (feeds the roofline, deliverable g).
 
 Results are written incrementally to ``benchmarks/results/dryrun/*.json``
 (idempotent: existing results are skipped unless --force), so the sweep can
 be resumed after interruption.
+
+The placeholder devices come from ``XLA_FLAGS``: ``main()`` folds
+``--xla_force_host_platform_device_count=512`` into the caller's flags and
+pins the CPU platform before any backend starts; importing this module
+changes neither (library users set their own flags).
 
 Usage:
     python -m repro.launch.dryrun                        # full sweep
@@ -22,6 +25,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -30,7 +34,11 @@ import jax
 from repro.configs import ARCH_IDS, get_arch
 from repro.distributed.meshctx import use_mesh
 from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import derive_terms
+from repro.launch.roofline import derive_terms, peaks
+
+# the chip the placeholder devices stand in for: its peaks (HBM capacity,
+# FLOP/s, bandwidths) come from the roofline table
+MODELED_KIND = "TPU v5 lite"
 
 RESULTS_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
@@ -77,7 +85,7 @@ def run_cell(arch_id: str, cell_name: str, mesh_name: str,
     cell = {c.name: c for c in arch.shapes()}[cell_name]
     record = {
         "arch": arch_id, "cell": cell_name, "mesh": mesh_name,
-        "kind": cell.kind, "status": "pending",
+        "kind": cell.kind, "device_kind": MODELED_KIND, "status": "pending",
     }
     if cell.skip:
         record.update(status="skipped", reason=cell.skip)
@@ -183,7 +191,7 @@ def run_cell(arch_id: str, cell_name: str, mesh_name: str,
 
         terms = derive_terms(
             arch_id, cell_name, mesh_name, chips, cost, hlo,
-            model_flops=arch.model_flops(cell),
+            model_flops=arch.model_flops(cell), device_kind=MODELED_KIND,
         )
         # overwrite with audited per-device numbers (x chips = global)
         terms.hlo_flops = flops_dev * chips
@@ -199,7 +207,7 @@ def run_cell(arch_id: str, cell_name: str, mesh_name: str,
             compile_s=round(t_compile, 2),
             memory=mem_json,
             per_device_bytes=per_dev,
-            fits_hbm=bool(per_dev < 16e9),   # v5e: 16 GB HBM
+            fits_hbm=bool(per_dev < peaks(MODELED_KIND).hbm_bytes),
             cost={k: cost[k] for k in ("flops", "bytes accessed")
                   if k in cost},
             cost_audit=cost_audit,
@@ -230,7 +238,17 @@ def _write(path: str, record: dict) -> None:
     os.replace(tmp, path)
 
 
+def _placeholder_devices(n: int = 512) -> None:
+    """Give the CPU backend ``n`` devices and pin it, keeping the caller's
+    other XLA flags.  Must run before the first JAX backend call."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    os.environ["XLA_FLAGS"] = (
+        f"{flags} --xla_force_host_platform_device_count={n}".strip())
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main() -> None:
+    _placeholder_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, help="one arch id (default: all)")
     ap.add_argument("--cell", default=None, help="one cell name (default: all)")

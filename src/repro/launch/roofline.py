@@ -2,9 +2,9 @@
 
 Per (arch x shape x mesh):
 
-    compute    = HLO_FLOPs / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes / (chips * HBM_BW)
-    collective = collective_bytes / (chips * ICI_BW)
+    compute    = HLO_FLOPs / (chips * peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips * HBM bytes/s)
+    collective = collective_bytes / (chips * ICI bytes/s per link)
 
 ``cost_analysis()`` provides FLOPs / bytes (whole-program totals across
 devices on recent JAX; detected and normalized).  Collective bytes are NOT in
@@ -13,7 +13,8 @@ of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute.  HLO shapes are per-device; multiplying by chip count
 gives the global volume the formulas above divide back down.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware peaks live in one table keyed by ``device_kind`` (as JAX reports
+it); a kind missing from the table is an error, never a default.
 """
 
 from __future__ import annotations
@@ -21,9 +22,30 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12        # bf16 per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link per chip
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    flops_bf16: float       # FLOP/s per chip
+    hbm_bw: float           # HBM bytes/s per chip
+    hbm_bytes: float        # HBM capacity per chip
+    ici_bw: float           # interconnect bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s inter-chip interconnect over 4 links.
+PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                               ici_bw=1600e9 / 8 / 4),
+}
+
+
+def peaks(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -69,14 +91,16 @@ class RooflineTerms:
     hlo_bytes: float            # global HBM bytes accessed
     collective_bytes: float     # global collective bytes
     model_flops: float          # analytic (6ND etc.)
+    device_kind: str            # the chip whose peaks the terms use
     compute_s: float = 0.0
     memory_s: float = 0.0
     collective_s: float = 0.0
 
     def __post_init__(self):
-        self.compute_s = self.hlo_flops / (self.chips * PEAK_FLOPS)
-        self.memory_s = self.hlo_bytes / (self.chips * HBM_BW)
-        self.collective_s = self.collective_bytes / (self.chips * ICI_BW)
+        p = peaks(self.device_kind)
+        self.compute_s = self.hlo_flops / (self.chips * p.flops_bf16)
+        self.memory_s = self.hlo_bytes / (self.chips * p.hbm_bw)
+        self.collective_s = self.collective_bytes / (self.chips * p.ici_bw)
 
     @property
     def dominant(self) -> str:
@@ -97,13 +121,13 @@ class RooflineTerms:
     def roofline_fraction(self) -> float:
         """Fraction of the compute roofline the dominant term allows:
         (model-flops time at peak) / (time the dominant term costs)."""
-        ideal = self.model_flops / (self.chips * PEAK_FLOPS)
+        ideal = self.model_flops / (self.chips * peaks(self.device_kind).flops_bf16)
         return ideal / max(self.bound_s, 1e-30)
 
     def to_json(self) -> dict:
         return {
             "arch": self.arch, "cell": self.cell, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "hlo_flops": self.hlo_flops, "hlo_bytes": self.hlo_bytes,
             "collective_bytes": self.collective_bytes,
             "model_flops": self.model_flops,
@@ -123,6 +147,7 @@ def derive_terms(
     cost: dict,
     hlo_text: str,
     model_flops: float,
+    device_kind: str,
     flops_are_global: bool = True,
 ) -> RooflineTerms:
     flops = float(cost.get("flops", 0.0))
@@ -135,5 +160,5 @@ def derive_terms(
     return RooflineTerms(
         arch=arch_id, cell=cell_name, mesh=mesh_name, chips=chips,
         hlo_flops=flops, hlo_bytes=byts, collective_bytes=coll_global,
-        model_flops=model_flops,
+        model_flops=model_flops, device_kind=device_kind,
     )

@@ -2,8 +2,10 @@
 
 Picks an architecture from the registry, builds the (elastic) mesh, the
 stateless data pipeline, and runs the fault-tolerant training loop with
-checkpointing.  On this CPU container use ``--reduced`` (the full configs
-are dry-run-only).
+checkpointing.  Full configs run on the chip; on a CPU use ``--reduced``.
+With ``--use-mesh`` the state and every batch are placed with the arch's
+sharding rules over all local devices.  The process exits non-zero when a
+step fails (an exception, or a loss that is not finite).
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b --reduced \
         --steps 200 --ckpt-dir /tmp/ck
@@ -12,6 +14,8 @@ are dry-run-only).
 from __future__ import annotations
 
 import argparse
+import dataclasses
+from typing import Callable, Optional
 
 import jax
 import numpy as np
@@ -21,7 +25,7 @@ from repro.data.pipeline import StatelessPipeline, lm_batch_maker, recsys_batch_
 from repro.distributed.fault import PreemptionGuard
 from repro.distributed.meshctx import use_mesh
 from repro.launch.mesh import make_elastic_mesh
-from repro.train.loop import TrainLoopConfig, run_training
+from repro.train.loop import TrainLoopConfig, TrainResult, run_training
 
 
 def _make_pipeline(arch, cell, reduced: bool):
@@ -44,6 +48,69 @@ def _make_pipeline(arch, cell, reduced: bool):
     return StatelessPipeline(make)
 
 
+@dataclasses.dataclass
+class TrainRun:
+    """What a training run produced, plus the pieces that built it: the
+    un-jitted step, the batch maker and the shardings (None without a mesh)."""
+
+    result: TrainResult
+    step_fn: Callable
+    batch_at: Callable[[int], dict]
+    mesh: Optional[jax.sharding.Mesh]
+    state_shardings: Optional[dict]
+    batch_shardings: Optional[dict]
+
+    @property
+    def failed_steps(self) -> list[int]:
+        return [i for i, loss in enumerate(self.result.losses)
+                if not np.isfinite(loss)]
+
+
+def train(arch_id: str, cell_name: Optional[str] = None, steps: int = 100,
+          reduced: bool = False, mesh_devices: bool = False,
+          ckpt_dir: Optional[str] = None, ckpt_every: int = 50,
+          log: Optional[str] = None) -> TrainRun:
+    arch = get_arch(arch_id)
+    cells = [c for c in arch.shapes() if c.kind == "train" and not c.skip]
+    cell = next((c for c in cells if c.name == cell_name), cells[0])
+    print(f"training {arch_id} on cell {cell.name} "
+          f"(reduced={reduced}, devices={len(jax.devices())})")
+
+    mesh = make_elastic_mesh() if mesh_devices else None
+    try:
+        step_fn = arch.make_step(cell, reduced=reduced, mesh=mesh)
+    except TypeError:
+        step_fn = arch.make_step(cell, reduced=reduced)
+
+    def init():
+        try:
+            return arch.init_state(jax.random.PRNGKey(0), cell,
+                                   reduced=reduced, mesh=mesh)
+        except TypeError:
+            return arch.init_state(jax.random.PRNGKey(0), cell,
+                                   reduced=reduced)
+
+    state_sh = batch_sh = None
+    if mesh is not None:
+        state_sh, batch_sh = arch.shardings(mesh, cell, reduced)
+    pipeline = _make_pipeline(arch, cell, reduced)
+    guard = PreemptionGuard(install=True)
+    cfg = TrainLoopConfig(
+        total_steps=steps,
+        checkpoint_every=ckpt_every,
+        checkpoint_dir=ckpt_dir,
+        log_path=log,
+    )
+    try:
+        with use_mesh(mesh):
+            result = run_training(init, step_fn, pipeline, cfg, preemption=guard,
+                                  shardings=state_sh, batch_shardings=batch_sh)
+    finally:
+        pipeline.close()
+    return TrainRun(result=result, step_fn=step_fn, batch_at=pipeline.batch_at,
+                    mesh=mesh, state_shardings=state_sh, batch_shardings=batch_sh)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
@@ -57,42 +124,21 @@ def main() -> None:
                     help="build an elastic mesh over available devices")
     args = ap.parse_args()
 
-    arch = get_arch(args.arch)
-    cells = [c for c in arch.shapes() if c.kind == "train" and not c.skip]
-    cell = next((c for c in cells if c.name == args.cell), cells[0])
-    print(f"training {args.arch} on cell {cell.name} "
-          f"(reduced={args.reduced}, devices={len(jax.devices())})")
-
-    mesh = make_elastic_mesh() if args.use_mesh else None
-    try:
-        step_fn = arch.make_step(cell, reduced=args.reduced, mesh=mesh)
-    except TypeError:
-        step_fn = arch.make_step(cell, reduced=args.reduced)
-
-    def init():
-        try:
-            return arch.init_state(jax.random.PRNGKey(0), cell,
-                                   reduced=args.reduced, mesh=mesh)
-        except TypeError:
-            return arch.init_state(jax.random.PRNGKey(0), cell,
-                                   reduced=args.reduced)
-
-    pipeline = _make_pipeline(arch, cell, args.reduced)
-    guard = PreemptionGuard(install=True)
-    cfg = TrainLoopConfig(
-        total_steps=args.steps,
-        checkpoint_every=args.ckpt_every,
-        checkpoint_dir=args.ckpt_dir,
-        log_path=args.log,
-    )
-    with use_mesh(mesh):
-        result = run_training(init, step_fn, pipeline, cfg, preemption=guard)
-    pipeline.close()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    run = train(args.arch, args.cell, steps=args.steps, reduced=args.reduced,
+                mesh_devices=args.use_mesh, ckpt_dir=args.ckpt_dir,
+                ckpt_every=args.ckpt_every, log=args.log)
+    result = run.result
     print(f"steps run: {result.steps_run}  resumed_from: {result.resumed_from}")
-    print(f"loss: {np.mean(result.losses[:5]):.4f} -> "
-          f"{np.mean(result.losses[-5:]):.4f}")
+    if result.losses:
+        print(f"loss: {np.mean(result.losses[:5]):.4f} -> "
+              f"{np.mean(result.losses[-5:]):.4f}")
     if result.straggler_steps:
         print(f"straggler steps: {result.straggler_steps}")
+    if run.failed_steps:
+        print(f"non-finite loss at steps {run.failed_steps}")
+        raise SystemExit(1)
 
 
 if __name__ == "__main__":

@@ -50,8 +50,13 @@ def run_training(
     config: TrainLoopConfig,
     preemption: Optional[PreemptionGuard] = None,
     shardings=None,
+    batch_shardings=None,
 ) -> TrainResult:
-    """Run (or resume) training to ``total_steps``."""
+    """Run (or resume) training to ``total_steps``.
+
+    ``shardings`` places the initial (or restored) state and
+    ``batch_shardings`` every batch; without them arrays go to the default
+    device."""
     logger = MetricsLogger(config.log_path, config.log_every)
     straggler = StragglerDetector(threshold=config.straggler_threshold)
     heartbeat = HeartbeatRegistry()
@@ -60,6 +65,8 @@ def run_training(
     # ---- resume ------------------------------------------------------------
     resumed_from = None
     state = init_state_fn()
+    if shardings is not None:
+        state = jax.device_put(state, shardings)
     if config.checkpoint_dir:
         last = latest_step(config.checkpoint_dir)
         if last is not None:
@@ -79,7 +86,7 @@ def run_training(
         for step, batch in pipeline.iterate(start_step,
                                             config.total_steps - start_step):
             t0 = time.perf_counter()
-            batch = jax.tree.map(jax.numpy.asarray, batch)
+            batch = jax.device_put(batch, batch_shardings)
             state, metrics = step_jit(state, batch)
             loss = float(metrics["loss"])
             losses.append(loss)

@@ -21,7 +21,10 @@ def _run(code: str, devices: int = 8, timeout: int = 600) -> str:
         f"'--xla_force_host_platform_device_count={devices}'\n"
         + textwrap.dedent(code)
     )
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    # children force host devices: they never touch an accelerator, which
+    # belongs to one process at a time
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-c", prog], capture_output=True, text=True,
         timeout=timeout, env=env, cwd=REPO,

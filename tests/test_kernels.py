@@ -248,3 +248,127 @@ def test_tree_stack_unstack_roundtrip():
         assert jax.tree.structure(orig) == jax.tree.structure(got)
         for a, b in zip(jax.tree.leaves(orig), jax.tree.leaves(got)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# custom VJPs: gradients through the kernels match the references'
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def interpret_mode(monkeypatch):
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+
+
+def _grad_pair(kernel_fn, ref_fn, args, argnums):
+    """(value, grads) of sum(out * cot) for the kernel path and the ref."""
+    out = ref_fn(*args)
+    cot = jnp.asarray(_rng(99).standard_normal(out.shape), out.dtype)
+    loss = lambda fn: (lambda *a: (fn(*a) * cot).sum())
+    got = jax.value_and_grad(loss(kernel_fn), argnums=argnums)(*args)
+    want = jax.value_and_grad(loss(ref_fn), argnums=argnums)(*args)
+    return got, want
+
+
+def _assert_grads_close(got, want, tol):
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=tol, atol=tol)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=tol, atol=tol)
+
+
+def test_edge_segment_sum_grad_matches_ref(interpret_mode):
+    from repro.kernels import ops
+
+    rng = _rng(21)
+    e, n, d = 700, 90, 16
+    values = jnp.asarray(rng.standard_normal((e, d)), jnp.float32)
+    # ids outside [0, N) are dropped forward and must get no gradient
+    dst = jnp.asarray(rng.integers(-3, n + 5, size=e), jnp.int32)
+    got, want = _grad_pair(lambda v: ops.edge_segment_sum(v, dst, n),
+                           lambda v: ref.edge_segment_sum(v, dst, n),
+                           (values,), 0)
+    _assert_grads_close(got, want, 1e-4)
+    assert np.all(np.asarray(got[1])[np.asarray((dst < 0) | (dst >= n))] == 0)
+
+
+def test_csr_segment_sum_grad_matches_ref(interpret_mode):
+    from repro.kernels import ops
+
+    rng = _rng(22)
+    n, d = 70, 8
+    deg = rng.integers(0, 12, size=n)
+    indptr = jnp.asarray(np.concatenate([[0], np.cumsum(deg)]), jnp.int32)
+    values = jnp.asarray(rng.standard_normal((int(deg.sum()), d)), jnp.float32)
+    got, want = _grad_pair(lambda v: ops.csr_segment_sum(v, indptr, n),
+                           lambda v: ref.csr_segment_sum(v, indptr, n),
+                           (values,), 0)
+    _assert_grads_close(got, want, 1e-4)
+
+
+def test_embedding_bag_grad_matches_ref(interpret_mode):
+    from repro.kernels import ops
+
+    rng = _rng(23)
+    v, d, b, l = 300, 8, 40, 5
+    table = jnp.asarray(rng.standard_normal((v, d)), jnp.float32)
+    idx = rng.integers(0, v, size=(b, l))
+    w = (rng.random((b, l)) < 0.8).astype(np.float32) * rng.random((b, l))
+    idx[w == 0] = -1                      # padding slots: index -1, weight 0
+    idx = jnp.asarray(idx, jnp.int32)
+    w = jnp.asarray(w, jnp.float32)
+    safe = jnp.clip(idx, 0, v - 1)        # the ref gathers; mask by weight 0
+    got, want = _grad_pair(lambda t, w: ops.embedding_bag(t, idx, w),
+                           lambda t, w: ref.embedding_bag(t, safe, w),
+                           (table, w), (0, 1))
+    # padding slots: the kernel gives no weight gradient, the ref's gather
+    # of a clipped row does; compare live slots only
+    live = np.asarray(w) != 0
+    _assert_grads_close((got[0], got[1][0]), (want[0], want[1][0]), 1e-4)
+    np.testing.assert_allclose(np.asarray(got[1][1])[live],
+                               np.asarray(want[1][1])[live], rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("causal,kv_len", [(True, None), (False, 200)])
+def test_flash_attention_grad_matches_ref(interpret_mode, causal, kv_len):
+    from repro.kernels import ops
+
+    rng = _rng(24)
+    b, h, s, dh = 1, 2, 256, 32
+    q, k, v = (jnp.asarray(rng.standard_normal((b, h, s, dh)) * 0.3, jnp.float32)
+               for _ in range(3))
+    mask = None if kv_len is None else jnp.asarray(kv_len, jnp.int32)
+    got, want = _grad_pair(
+        lambda q, k, v: ops.flash_attention(q, k, v, causal=causal, block_q=64,
+                                            block_kv=64, kv_len_mask=mask),
+        lambda q, k, v: ref.attention(q, k, v, causal=causal, kv_len=kv_len),
+        (q, k, v), (0, 1, 2))
+    _assert_grads_close(got, want, 2e-4)
+
+
+# ---------------------------------------------------------------------------
+# no hidden fallback
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("forced", ["interpret", "off"])
+def test_forced_mode_is_an_error_on_tpu(monkeypatch, forced):
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_PALLAS", forced)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="REPRO_PALLAS"):
+        ops.use_pallas()
+
+
+def test_unknown_pallas_mode_is_an_error(monkeypatch):
+    from repro.kernels import ops
+
+    monkeypatch.setenv("REPRO_PALLAS", "sometimes")
+    with pytest.raises(ValueError, match="REPRO_PALLAS"):
+        ops.use_pallas()
+
+
+def test_flash_attention_refuses_ragged_blocks(interpret_mode):
+    from repro.kernels import ops
+
+    q = jnp.zeros((1, 1, 96, 32), jnp.float32)
+    with pytest.raises(ValueError, match="do not divide"):
+        ops.flash_attention(q, q, q, block_q=64, block_kv=64)
