@@ -1,0 +1,22 @@
+"""Published peaks per chip, keyed by ``device_kind`` as JAX reports it.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture): per chip
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of HBM at 819 GB/s.  Copied from the
+repo's ``launch/roofline.py`` table.  A kind that is not here is an error,
+never a default.
+"""
+
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
