@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.kernels import ops as kops
 from repro.perf_flags import enabled as perf_enabled
+from repro.tracing import scope, span
 
 
 def _csr_for(engine, edge_type: str, n: int):
@@ -63,19 +64,34 @@ def _edges_dst_sorted(engine, edge_type: str, n: int):
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def _pagerank_step_csr(rank, rev_src, rev_indptr, out_deg, n: int, damping: float):
-    contrib = rank[rev_src] / jnp.maximum(out_deg[rev_src], 1.0)
-    agg = kops.csr_segment_sum(contrib, rev_indptr, n)
-    # dangling mass (vertices with no out-edges) redistributes uniformly
-    dangling = jnp.where(out_deg > 0, 0.0, rank).sum()
+    with scope("pagerank.gather"):
+        contrib = rank[rev_src] / jnp.maximum(out_deg[rev_src], 1.0)
+    with scope("pagerank.segment_sum"):
+        agg = kops.csr_segment_sum(contrib, rev_indptr, n)
+    with scope("pagerank.dangling"):
+        # dangling mass (vertices with no out-edges) redistributes uniformly
+        dangling = jnp.where(out_deg > 0, 0.0, rank).sum()
     return (1.0 - damping) / n + damping * (agg + dangling / n)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def _pagerank_step(rank, src, dst, out_deg, n: int, damping: float):
-    contrib = rank[src] / jnp.maximum(out_deg[src], 1.0)
-    agg = kops.segment_sum(contrib, dst, n)
-    dangling = jnp.where(out_deg > 0, 0.0, rank).sum()
+    with scope("pagerank.gather"):
+        contrib = rank[src] / jnp.maximum(out_deg[src], 1.0)
+    with scope("pagerank.segment_sum"):
+        agg = kops.segment_sum(contrib, dst, n)
+    with scope("pagerank.dangling"):
+        dangling = jnp.where(out_deg > 0, 0.0, rank).sum()
     return (1.0 - damping) / n + damping * (agg + dangling / n)
+
+
+def _upload(*arrays) -> tuple:
+    """Host arrays onto the device, waited for, in a ``pagerank.upload``
+    span that carries their bytes."""
+    with span("pagerank.upload") as s:
+        out = jax.block_until_ready(tuple(jnp.asarray(a, dtype=d) for a, d in arrays))
+        s.set_metadata(bytes=sum(int(x.nbytes) for x in out))
+    return out
 
 
 def pagerank(engine, edge_type: str, n: int | None = None, damping: float = 0.85,
@@ -84,24 +100,26 @@ def pagerank(engine, edge_type: str, n: int | None = None, damping: float = 0.85
     n = n or engine.topology.n_vertices(et.src_type)
     csr = _csr_for(engine, edge_type, n)
     if csr is not None:
-        rev_src = jnp.asarray(csr.rev_src, dtype=jnp.int32)
-        rev_indptr = jnp.asarray(csr.rev_indptr, dtype=jnp.int32)
-        out_deg = jnp.asarray(csr.degrees("out"), dtype=jnp.float32)
+        rev_src, rev_indptr, out_deg = _upload(
+            (csr.rev_src, jnp.int32), (csr.rev_indptr, jnp.int32),
+            (csr.degrees("out"), jnp.float32))
         step = lambda r: _pagerank_step_csr(r, rev_src, rev_indptr, out_deg, n, damping)
     else:
         src, dst = engine.concat_edges(edge_type)
-        src_j = jnp.asarray(src, dtype=jnp.int32)
-        dst_j = jnp.asarray(dst, dtype=jnp.int32)
+        src_j, dst_j = _upload((src, jnp.int32), (dst, jnp.int32))
         out_deg = kops.segment_sum(jnp.ones_like(src_j, dtype=jnp.float32), src_j, n)
         step = lambda r: _pagerank_step(r, src_j, dst_j, out_deg, n, damping)
     rank = jnp.full(n, 1.0 / n, dtype=jnp.float32)
-    for _ in range(max_iters):
-        new = step(rank)
-        if float(jnp.abs(new - rank).sum()) < tol:
-            rank = new
-            break
+    for i in range(max_iters):
+        with span("pagerank.superstep", step=i):
+            new = step(rank)
+            with span("pagerank.sync"):
+                done = float(jnp.abs(new - rank).sum()) < tol
         rank = new
-    return np.asarray(rank)
+        if done:
+            break
+    with span("pagerank.readback"):
+        return np.asarray(rank)
 
 
 # ---------------------------------------------------------------------------

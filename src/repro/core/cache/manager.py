@@ -57,6 +57,7 @@ from repro.core.cache.units import ChunkRef, EdgeCacheUnit, NaiveChunkReader, Ve
 from repro.lakehouse.columnfile import ColumnFileMeta
 from repro.lakehouse.objectstore import ObjectStore
 from repro.lakehouse.retry import lake_get
+from repro.tracing import span
 
 
 @dataclasses.dataclass
@@ -208,8 +209,9 @@ class CacheManager:
         chunk = meta.chunk(ref.column, ref.row_group)
         # lake_get retries transient faults and rejects short (torn) reads
         # against the chunk length, so truncated bytes never enter the cache
-        raw = lake_get(self.store, meta.key,
-                       offset=chunk.offset, length=chunk.length)
+        with span("lake.fetch", bytes=chunk.length):
+            raw = lake_get(self.store, meta.key,
+                           offset=chunk.offset, length=chunk.length)
         with self._lock:
             self.stats["lake_fetches"] += 1
             self._disk_put_raw(key, raw)

@@ -45,6 +45,7 @@ import threading
 import numpy as np
 
 from repro.lakehouse.encoding import decode_column
+from repro.tracing import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -88,7 +89,8 @@ class VertexCacheUnit:
         # the substrate decoder decodes prefixes natively (see encoding.py), so
         # extending the prefix costs only the *new* rows' decode work but one
         # pass over the stream; we count decoded rows as the work unit.
-        decoded = decode_column(self._raw, row_limit=upto)
+        with span("lake.decode", rows=upto):
+            decoded = decode_column(self._raw, row_limit=upto)
         if self._values is None:
             # pre-allocate full capacity once: avoids resize/copy churn (§5.1)
             if decoded.dtype == object:
@@ -163,7 +165,8 @@ class EdgeCacheUnit:
         # the encoded stream decodes prefixes; a window [start, stop) costs a
         # prefix decode to `stop` (streams are not backward-seekable), but we
         # only *retain* the window — bounded memory, amortized batch decode.
-        decoded = decode_column(self._raw, row_limit=stop)
+        with span("lake.decode", rows=stop):
+            decoded = decode_column(self._raw, row_limit=stop)
         self._buf = decoded[start:stop]
         self._buf_start = start
         self.decode_ops += stop - start

@@ -54,6 +54,7 @@ from repro.core.read_pipeline import (
     plan_vertex_read_multi,
 )
 from repro.core.types import VSet
+from repro.tracing import span
 
 
 def _finalize(out: dict, n: int) -> dict[str, np.ndarray]:
@@ -199,10 +200,11 @@ def vertex_map(
         prefetcher.prefetch_vertices(vset, columns, bounds=bounds, topo=topology)
     ids = vset.ids()
     frame = {"id": ids}
-    cols, reject = read_vertex_columns_pruned(
-        topology, cache, vset.vertex_type, ids, list(columns),
-        bounds=bounds, counters=counters, pool=pool,
-    )
+    with span("read.seed", rows=len(ids), columns=";".join(columns)):
+        cols, reject = read_vertex_columns_pruned(
+            topology, cache, vset.vertex_type, ids, list(columns),
+            bounds=bounds, counters=counters, pool=pool,
+        )
     frame.update(cols)
     out_vals = map_fn(frame) if map_fn is not None else None
     if filter_fn is not None:
@@ -217,6 +219,18 @@ def vertex_map(
 # ---------------------------------------------------------------------------
 # EdgeScan
 # ---------------------------------------------------------------------------
+
+def _gather(topology, edge_type, strategy, frontier, direction):
+    """(u, v, global eid) of the edges incident to ``frontier``, through
+    the representation the topology plane picks for this scan."""
+    with span("scan.gather") as s:
+        view = topology.plane.view(
+            edge_type, strategy, frontier=frontier, direction=direction
+        )
+        u, v, eid = view.gather(frontier, direction=direction)
+        s.set_metadata(rows=len(u))
+    return u, v, eid
+
 
 @dataclasses.dataclass
 class EdgeFrame:
@@ -304,10 +318,7 @@ def edge_scan(
                                   direction=direction, topo=topology)
         prefetcher.prefetch_vertices(frontier, u_columns, topo=topology)
 
-    view = topology.plane.view(
-        edge_type, strategy, frontier=frontier, direction=direction
-    )
-    u, v, eid = view.gather(frontier, direction=direction)
+    u, v, eid = _gather(topology, edge_type, strategy, frontier, direction)
     ctx = ReadContext()
     by_col, _ = read_edge_columns_pruned(
         topology, cache, edge_type, eid, edge_columns, counters=counters,
@@ -452,10 +463,7 @@ def edge_scan_batched(
     else:
         u_type, v_type = et.dst_type, et.src_type
 
-    view = topology.plane.view(
-        edge_type, strategy, frontier=union, direction=direction
-    )
-    u, v, eid = view.gather(union, direction=direction)
+    u, v, eid = _gather(topology, edge_type, strategy, union, direction)
     alive = np.stack([f.mask[u] for f in frontiers]) if len(u) \
         else np.zeros((len(frontiers), 0), dtype=bool)
     ctx = ReadContext()
@@ -466,16 +474,18 @@ def edge_scan_batched(
         rider keeps."""
         nonlocal u, v, eid, alive, columns
         columns.update(prefix_cols)
-        if len(u):
-            frame = dict(columns)
-            frame["u"] = u
-            frame["v"] = v
-            for r, pred in enumerate(preds):
-                if pred is None:
-                    continue
-                keep = np.asarray(pred.evaluate(frame, prefix), dtype=bool)
-                alive[r] &= keep & ~rejects[r]
-        keep_any = alive.any(axis=0)
+        with span(f"predicate.{prefix.upper()}", rows_in=len(u)) as s:
+            if len(u):
+                frame = dict(columns)
+                frame["u"] = u
+                frame["v"] = v
+                for r, pred in enumerate(preds):
+                    if pred is None:
+                        continue
+                    keep = np.asarray(pred.evaluate(frame, prefix), dtype=bool)
+                    alive[r] &= keep & ~rejects[r]
+            keep_any = alive.any(axis=0)
+            s.set_metadata(rows_out=int(np.count_nonzero(keep_any)))
         if keep_any.all():
             return
         u, v, eid = u[keep_any], v[keep_any], eid[keep_any]
@@ -484,29 +494,34 @@ def edge_scan_batched(
 
     if e_cols:
         check_deadline(deadline)
-        cols, rejects = read_edge_columns_multi(
-            topology, cache, edge_type, eid, e_cols,
-            [p.edge_bounds for p in plans], counters=counters, pool=pool,
-            ctx=ctx,
-        )
+        with span("read.E", rows=len(eid), columns=";".join(e_cols)):
+            cols, rejects = read_edge_columns_multi(
+                topology, cache, edge_type, eid, e_cols,
+                [p.edge_bounds for p in plans], counters=counters, pool=pool,
+                ctx=ctx,
+            )
         _evaluate([p.edge_pred for p in plans], "e",
                   {f"e.{c}": a for c, a in cols.items()}, rejects)
 
     if u_cols:
         check_deadline(deadline)
-        cols, rejects = read_vertex_columns_multi(
-            topology, cache, u_type, u, u_cols,
-            [p.u_bounds for p in plans], counters=counters, pool=pool, ctx=ctx,
-        )
+        with span("read.U", rows=len(u), columns=";".join(u_cols)):
+            cols, rejects = read_vertex_columns_multi(
+                topology, cache, u_type, u, u_cols,
+                [p.u_bounds for p in plans], counters=counters, pool=pool,
+                ctx=ctx,
+            )
         _evaluate([p.source_pred for p in plans], "u",
                   {f"u.{c}": a for c, a in cols.items()}, rejects)
 
     if v_cols:
         check_deadline(deadline)
-        cols, rejects = read_vertex_columns_multi(
-            topology, cache, v_type, v, v_cols,
-            [p.v_bounds for p in plans], counters=counters, pool=pool, ctx=ctx,
-        )
+        with span("read.V", rows=len(v), columns=";".join(v_cols)):
+            cols, rejects = read_vertex_columns_multi(
+                topology, cache, v_type, v, v_cols,
+                [p.v_bounds for p in plans], counters=counters, pool=pool,
+                ctx=ctx,
+            )
         _evaluate([p.target_pred for p in plans], "v",
                   {f"v.{c}": a for c, a in cols.items()}, rejects)
 
@@ -518,22 +533,25 @@ def edge_scan_batched(
     if acc_e or acc_u or acc_v:
         check_deadline(deadline)
     if acc_e:
-        cols, _ = read_edge_columns_multi(
-            topology, cache, edge_type, eid, acc_e, [{}], counters=counters,
-            pool=pool, ctx=ctx,
-        )
+        with span("read.accum", rows=len(eid), columns=";".join(acc_e)):
+            cols, _ = read_edge_columns_multi(
+                topology, cache, edge_type, eid, acc_e, [{}], counters=counters,
+                pool=pool, ctx=ctx,
+            )
         columns.update({f"e.{c}": a for c, a in cols.items()})
     if acc_u:
-        cols, _ = read_vertex_columns_multi(
-            topology, cache, u_type, u, acc_u, [{}], counters=counters,
-            pool=pool, ctx=ctx,
-        )
+        with span("read.accum", rows=len(u), columns=";".join(acc_u)):
+            cols, _ = read_vertex_columns_multi(
+                topology, cache, u_type, u, acc_u, [{}], counters=counters,
+                pool=pool, ctx=ctx,
+            )
         columns.update({f"u.{c}": a for c, a in cols.items()})
     if acc_v:
-        cols, _ = read_vertex_columns_multi(
-            topology, cache, v_type, v, acc_v, [{}], counters=counters,
-            pool=pool, ctx=ctx,
-        )
+        with span("read.accum", rows=len(v), columns=";".join(acc_v)):
+            cols, _ = read_vertex_columns_multi(
+                topology, cache, v_type, v, acc_v, [{}], counters=counters,
+                pool=pool, ctx=ctx,
+            )
         columns.update({f"v.{c}": a for c, a in cols.items()})
 
     return BatchedScan(u=u, v=v, u_type=u_type, v_type=v_type,
@@ -570,10 +588,7 @@ def _edge_scan_staged(
             bounds=plan.u_bounds, topo=topology,
         )
 
-    view = topology.plane.view(
-        edge_type, strategy, frontier=frontier, direction=direction
-    )
-    u, v, eid = view.gather(frontier, direction=direction)
+    u, v, eid = _gather(topology, edge_type, strategy, frontier, direction)
     ctx = ReadContext()
     columns: dict[str, np.ndarray] = {}
 
@@ -586,64 +601,74 @@ def _edge_scan_staged(
         frame = dict(columns)
         frame["u"] = u
         frame["v"] = v
-        keep = np.asarray(pred.evaluate(frame, prefix), dtype=bool) & ~reject
+        with span(f"predicate.{prefix.upper()}", rows_in=len(u)) as s:
+            keep = np.asarray(pred.evaluate(frame, prefix), dtype=bool) & ~reject
+            s.set_metadata(rows_out=int(np.count_nonzero(keep)))
         u, v, eid = u[keep], v[keep], eid[keep]
         columns = {k: vals[keep] for k, vals in columns.items()}
 
     if plan.edge_columns:
         check_deadline(deadline)
-        e_cols, rej = read_edge_columns_pruned(
-            topology, cache, edge_type, eid, plan.edge_columns,
-            bounds=plan.edge_bounds, counters=counters, pool=pool, ctx=ctx,
-        )
+        with span("read.E", rows=len(eid), columns=";".join(plan.edge_columns)):
+            e_cols, rej = read_edge_columns_pruned(
+                topology, cache, edge_type, eid, plan.edge_columns,
+                bounds=plan.edge_bounds, counters=counters, pool=pool, ctx=ctx,
+            )
         _evaluate(plan.edge_pred, "e", {f"e.{c}": a for c, a in e_cols.items()}, rej)
 
     if plan.u_columns:
         check_deadline(deadline)
-        u_cols, rej = read_vertex_columns_pruned(
-            topology, cache, u_type, u, plan.u_columns,
-            bounds=plan.u_bounds, counters=counters, pool=pool, ctx=ctx,
-        )
+        with span("read.U", rows=len(u), columns=";".join(plan.u_columns)):
+            u_cols, rej = read_vertex_columns_pruned(
+                topology, cache, u_type, u, plan.u_columns,
+                bounds=plan.u_bounds, counters=counters, pool=pool, ctx=ctx,
+            )
         _evaluate(plan.source_pred, "u", {f"u.{c}": a for c, a in u_cols.items()}, rej)
 
     if plan.v_columns:
         check_deadline(deadline)
-        if read_v_values is not None:
-            v_cols = {c: read_v_values(v_type, v, c) for c in plan.v_columns}
-            rej = np.zeros(len(v), dtype=bool)
-        else:
-            v_cols, rej = read_vertex_columns_pruned(
-                topology, cache, v_type, v, plan.v_columns,
-                bounds=plan.v_bounds, counters=counters, pool=pool, ctx=ctx,
-            )
+        with span("read.V", rows=len(v), columns=";".join(plan.v_columns)):
+            if read_v_values is not None:
+                v_cols = {c: read_v_values(v_type, v, c) for c in plan.v_columns}
+                rej = np.zeros(len(v), dtype=bool)
+            else:
+                v_cols, rej = read_vertex_columns_pruned(
+                    topology, cache, v_type, v, plan.v_columns,
+                    bounds=plan.v_bounds, counters=counters, pool=pool, ctx=ctx,
+                )
         _evaluate(plan.target_pred, "v", {f"v.{c}": a for c, a in v_cols.items()}, rej)
 
     # ACCUM-only columns: needed by no predicate -> final survivors only
     if plan.accum_edge_columns or plan.accum_u_columns or plan.accum_v_columns:
         check_deadline(deadline)
     if plan.accum_edge_columns:
-        e_cols, _ = read_edge_columns_pruned(
-            topology, cache, edge_type, eid, plan.accum_edge_columns,
-            counters=counters, pool=pool, ctx=ctx,
-        )
-        columns.update({f"e.{c}": a for c, a in e_cols.items()})
-    if plan.accum_u_columns:
-        u_cols, _ = read_vertex_columns_pruned(
-            topology, cache, u_type, u, plan.accum_u_columns,
-            counters=counters, pool=pool, ctx=ctx,
-        )
-        columns.update({f"u.{c}": a for c, a in u_cols.items()})
-    if plan.accum_v_columns:
-        if read_v_values is not None:
-            columns.update(
-                {f"v.{c}": read_v_values(v_type, v, c) for c in plan.accum_v_columns}
-            )
-        else:
-            v_cols, _ = read_vertex_columns_pruned(
-                topology, cache, v_type, v, plan.accum_v_columns,
+        with span("read.accum", rows=len(eid),
+                  columns=";".join(plan.accum_edge_columns)):
+            e_cols, _ = read_edge_columns_pruned(
+                topology, cache, edge_type, eid, plan.accum_edge_columns,
                 counters=counters, pool=pool, ctx=ctx,
             )
-            columns.update({f"v.{c}": a for c, a in v_cols.items()})
+        columns.update({f"e.{c}": a for c, a in e_cols.items()})
+    if plan.accum_u_columns:
+        with span("read.accum", rows=len(u),
+                  columns=";".join(plan.accum_u_columns)):
+            u_cols, _ = read_vertex_columns_pruned(
+                topology, cache, u_type, u, plan.accum_u_columns,
+                counters=counters, pool=pool, ctx=ctx,
+            )
+        columns.update({f"u.{c}": a for c, a in u_cols.items()})
+    if plan.accum_v_columns:
+        with span("read.accum", rows=len(v),
+                  columns=";".join(plan.accum_v_columns)):
+            if read_v_values is not None:
+                columns.update({f"v.{c}": read_v_values(v_type, v, c)
+                                for c in plan.accum_v_columns})
+            else:
+                v_cols, _ = read_vertex_columns_pruned(
+                    topology, cache, v_type, v, plan.accum_v_columns,
+                    counters=counters, pool=pool, ctx=ctx,
+                )
+                columns.update({f"v.{c}": a for c, a in v_cols.items()})
 
     return EdgeFrame(u=u, v=v, u_type=u_type, v_type=v_type, columns=columns,
                      eid=eid)

@@ -70,6 +70,7 @@ from repro.core.plan import (
     union_bounds_maps,
 )
 from repro.core.types import VSet
+from repro.tracing import span
 
 
 # ---------------------------------------------------------------------------
@@ -448,29 +449,24 @@ def _run_statement(eng, stmt: CompiledStatement, accums, counters, options,
     topo = epoch if epoch is not None else eng.topology
     pushdown, pipeline = options.pushdown, options.pipeline
 
-    if seed.raw_ids is not None:
-        vset = eng.vset_from_raw_ids(seed.vertex_type, seed.raw_ids, epoch=epoch)
-    else:
-        vset = eng.all_vertices(seed.vertex_type, epoch=epoch)
-    if seed.where is not None:
-        vset, _ = eng.vertex_map(
-            vset,
-            columns=list(dict.fromkeys(seed.where.columns)),
-            filter_fn=lambda fr: seed.where.evaluate(fr, ""),
-            bounds=seed.where.bounds() if pushdown else None,
-            counters=counters, pipeline=pipeline, epoch=epoch,
-            deadline=deadline,
-        )
-    if seed.accum_where:
-        n = topo.n_vertices(seed.vertex_type)
-        mask = vset.mask.copy()
-        for name, op, value in seed.accum_where:
-            if accums.has(seed.vertex_type, name):
-                arr = accums.ensure_capacity(seed.vertex_type, name, n)[:n]
-            else:  # never written -> every slot sits at the sum identity
-                arr = np.zeros(n)
-            mask &= _ACC_CMP[op](arr, value)
-        vset = VSet(seed.vertex_type, mask)
+    with span("query.seed", vertex_type=seed.vertex_type):
+        if seed.raw_ids is not None:
+            vset = eng.vset_from_raw_ids(seed.vertex_type, seed.raw_ids,
+                                         epoch=epoch)
+        else:
+            vset = eng.all_vertices(seed.vertex_type, epoch=epoch)
+        if seed.where is not None:
+            vset, _ = eng.vertex_map(
+                vset,
+                columns=list(dict.fromkeys(seed.where.columns)),
+                filter_fn=lambda fr: _seed_verdict(seed.where, fr),
+                bounds=seed.where.bounds() if pushdown else None,
+                counters=counters, pipeline=pipeline, epoch=epoch,
+                deadline=deadline,
+            )
+        if seed.accum_where:
+            vset = VSet(seed.vertex_type,
+                        vset.mask & _accum_seed_mask(accums, topo, seed))
     seed_set = vset
 
     aliases = stmt.vertex_aliases or [None] * (len(stmt.hops) + 1)
@@ -486,13 +482,12 @@ def _run_statement(eng, stmt: CompiledStatement, accums, counters, options,
     first_frame = None
     for hop_i, hop in enumerate(stmt.hops):
         check_deadline(deadline)
-        frame, u_type, v_type = _exec_hop(
-            eng, vset, hop, counters, options, epoch, deadline)
+        frame, v_type = _run_hop(eng, vset, hop, accums, topo, counters,
+                                 options, epoch, deadline, accum_out)
         if hop_i == 0:
             first_frame = frame
         n_scanned += len(frame)
         frames.append(frame)
-        _apply_accum(accums, topo, hop, frame, u_type, v_type, accum_out)
         n_v = topo.n_vertices(v_type)
         vset = frame.v_set(n_v)
         matched[hop_i + 1] = vset
@@ -508,16 +503,58 @@ def _run_statement(eng, stmt: CompiledStatement, accums, counters, options,
     for pb in stmt.post:
         check_deadline(deadline)
         src = matched_set(pb.source)
-        frame, u_type, v_type = _exec_hop(
-            eng, src, pb.hop, counters, options, epoch, deadline)
+        frame, v_type = _run_hop(eng, src, pb.hop, accums, topo, counters,
+                                 options, epoch, deadline, accum_out)
         n_scanned += len(frame)
         frames.append(frame)
-        _apply_accum(accums, topo, pb.hop, frame, u_type, v_type, accum_out)
         if pb.target_alias is not None:
             alias_sets[pb.target_alias] = frame.v_set(topo.n_vertices(v_type))
 
     select = stmt.select if stmt.select >= 0 else len(stmt.hops)
     return matched_set(select), n_scanned
+
+
+def _seed_verdict(where: Predicate, frame: dict) -> np.ndarray:
+    """The seed's WHERE over its VertexMap frame."""
+    with span("predicate.seed", rows_in=len(frame["id"])) as s:
+        keep = np.asarray(where.evaluate(frame, ""), dtype=bool)
+        s.set_metadata(rows_out=int(np.count_nonzero(keep)))
+    return keep
+
+
+def _accum_seed_mask(accums, topo, seed: _SeedBlock) -> np.ndarray:
+    """The seed's accumulator conjuncts against runtime @accum state."""
+    n = topo.n_vertices(seed.vertex_type)
+    mask = np.ones(n, dtype=bool)
+    for name, op, value in seed.accum_where:
+        if accums.has(seed.vertex_type, name):
+            arr = accums.ensure_capacity(seed.vertex_type, name, n)[:n]
+        else:  # never written -> every slot sits at the sum identity
+            arr = np.zeros(n)
+        mask &= _ACC_CMP[op](arr, value)
+    return mask
+
+
+def _hop_span(hops: list, frontiers: list):
+    """``query.hop`` over one hop: its edge type and direction, and the
+    frontier size (summed over riders for a shared scan)."""
+    return span("query.hop", edge_type=hops[0].edge_type,
+                direction=hops[0].direction, riders=len(hops),
+                rows_in=sum(int(np.count_nonzero(f.mask)) for f in frontiers))
+
+
+def _run_hop(eng, vset, hop: _HopBlock, accums, topo, counters, options,
+             epoch, deadline, accum_out):
+    """One hop and its ACCUM; returns the frame and the far-side type."""
+    with _hop_span([hop], [vset]) as s:
+        frame, u_type, v_type = _exec_hop(
+            eng, vset, hop, counters, options, epoch, deadline)
+        if hop.accum is not None:
+            with span("accum", rows=len(frame)):
+                _apply_accum(accums, topo, hop, frame, u_type, v_type,
+                             accum_out)
+        s.set_metadata(rows_out=len(frame))
+    return frame, v_type
 
 
 def _exec_hop(eng, vset, hop: _HopBlock, counters, options, epoch, deadline):
@@ -570,8 +607,6 @@ def _exec_hop(eng, vset, hop: _HopBlock, counters, options, epoch, deadline):
 
 
 def _apply_accum(accums, topo, hop: _HopBlock, frame, u_type, v_type, accum_out):
-    if hop.accum is None:
-        return
     a = hop.accum
     if a.target == "v":
         tgt_type, tgt_ids = v_type, frame.v
@@ -712,56 +747,13 @@ def _run_statement_batched(eng, stmts, accums_list, counters, options, epoch,
     """Lockstep batched :func:`_run_statement`: riders advance hop by hop
     through one shared scan per hop, each tracking its own frontier,
     matched sets, aliases and accumulators."""
-    from repro.core.primitives import edge_scan_batched, read_vertex_columns_multi
-
     n_riders = len(stmts)
     topo = epoch if epoch is not None else eng.topology
     pool = eng._query_pool(options.pipeline)
     seed0 = stmts[0].seed
-    base = eng.all_vertices(seed0.vertex_type, epoch=epoch)
-
-    # seed stage: one shared column read over the base set, per-rider
-    # evaluation — vertex_map's filter path lifted across riders
-    wheres = [s.seed.where for s in stmts]
-    if any(w is not None for w in wheres):
-        check_deadline(deadline)
-        columns = list(dict.fromkeys(
-            c for w in wheres if w is not None for c in w.columns))
-        bounds_list = [w.bounds() if w is not None else {} for w in wheres]
-        if eng.prefetcher is not None:
-            eng.prefetcher.prefetch_vertices(
-                base, columns, bounds=union_bounds_maps(bounds_list),
-                topo=eng._topo(epoch))
-        ids = base.ids()
-        cols, rejects = read_vertex_columns_multi(
-            eng._topo(epoch), eng.cache, seed0.vertex_type, ids, columns,
-            bounds_list, counters=counters, pool=pool,
-        )
-        frame = {"id": ids, **cols}
-        vsets = []
-        for r, w in enumerate(wheres):
-            if w is None:
-                vsets.append(base)
-                continue
-            keep = np.asarray(w.evaluate(frame, ""), dtype=bool) & ~rejects[r]
-            vsets.append(VSet.from_dense_ids(
-                seed0.vertex_type, len(base.mask), ids[keep]))
-    else:
-        vsets = [base] * n_riders
-
-    for r, s in enumerate(stmts):
-        seed = s.seed
-        if seed.accum_where:
-            n = topo.n_vertices(seed.vertex_type)
-            mask = vsets[r].mask.copy()
-            for name, op, value in seed.accum_where:
-                if accums_list[r].has(seed.vertex_type, name):
-                    arr = accums_list[r].ensure_capacity(
-                        seed.vertex_type, name, n)[:n]
-                else:  # never written -> every slot sits at the sum identity
-                    arr = np.zeros(n)
-                mask &= _ACC_CMP[op](arr, value)
-            vsets[r] = VSet(seed.vertex_type, mask)
+    with span("query.seed", vertex_type=seed0.vertex_type, riders=n_riders):
+        vsets = _seed_batched(eng, stmts, accums_list, topo, counters, pool,
+                              epoch, deadline)
     seed_sets = list(vsets)
 
     n_hops = len(stmts[0].hops)
@@ -778,14 +770,9 @@ def _run_statement_batched(eng, stmts, accums_list, counters, options, epoch,
     for hop_i in range(n_hops):
         check_deadline(deadline)
         hops = [s.hops[hop_i] for s in stmts]
-        scan = edge_scan_batched(
-            eng._topo(epoch), eng.cache, vsets, hops[0].edge_type,
-            hops[0].direction, [plan_hop(h) for h in hops],
-            prefetcher=eng.prefetcher, counters=counters, pool=pool,
-            deadline=deadline,
-        )
+        scan = _run_hop_batched(eng, vsets, hops, accums_list, topo, counters,
+                                pool, epoch, deadline, accum_outs)
         rider_frames = [scan.frame(r) for r in range(n_riders)]
-        _apply_accum_batched(accums_list, topo, hops, scan, accum_outs)
         n_v = topo.n_vertices(scan.v_type)
         for r in range(n_riders):
             if hop_i == 0:
@@ -808,14 +795,9 @@ def _run_statement_batched(eng, stmts, accums_list, counters, options, epoch,
         pbs = [s.post[pb_i] for s in stmts]
         hops = [pb.hop for pb in pbs]
         srcs = [matched_set(r, pbs[r].source) for r in range(n_riders)]
-        scan = edge_scan_batched(
-            eng._topo(epoch), eng.cache, srcs, hops[0].edge_type,
-            hops[0].direction, [plan_hop(h) for h in hops],
-            prefetcher=eng.prefetcher, counters=counters, pool=pool,
-            deadline=deadline,
-        )
+        scan = _run_hop_batched(eng, srcs, hops, accums_list, topo, counters,
+                                pool, epoch, deadline, accum_outs)
         rider_frames = [scan.frame(r) for r in range(n_riders)]
-        _apply_accum_batched(accums_list, topo, hops, scan, accum_outs)
         n_v = topo.n_vertices(scan.v_type)
         for r in range(n_riders):
             frames_list[r].append(rider_frames[r])
@@ -826,6 +808,72 @@ def _run_statement_batched(eng, stmts, accums_list, counters, options, epoch,
 
     sel = stmts[0].select if stmts[0].select >= 0 else n_hops
     return [matched_set(r, sel) for r in range(n_riders)]
+
+
+def _seed_batched(eng, stmts, accums_list, topo, counters, pool, epoch,
+                  deadline) -> list:
+    """Each rider's seed set: one shared column read over the base set,
+    per-rider evaluation — vertex_map's filter path lifted across riders."""
+    from repro.core.primitives import read_vertex_columns_multi
+
+    seed0 = stmts[0].seed
+    base = eng.all_vertices(seed0.vertex_type, epoch=epoch)
+    wheres = [s.seed.where for s in stmts]
+    if any(w is not None for w in wheres):
+        check_deadline(deadline)
+        columns = list(dict.fromkeys(
+            c for w in wheres if w is not None for c in w.columns))
+        bounds_list = [w.bounds() if w is not None else {} for w in wheres]
+        if eng.prefetcher is not None:
+            eng.prefetcher.prefetch_vertices(
+                base, columns, bounds=union_bounds_maps(bounds_list),
+                topo=eng._topo(epoch))
+        ids = base.ids()
+        with span("read.seed", rows=len(ids), columns=";".join(columns)):
+            cols, rejects = read_vertex_columns_multi(
+                eng._topo(epoch), eng.cache, seed0.vertex_type, ids, columns,
+                bounds_list, counters=counters, pool=pool,
+            )
+        frame = {"id": ids, **cols}
+        vsets = []
+        kept = np.zeros(len(ids), dtype=bool)    # rows some rider keeps
+        with span("predicate.seed", rows_in=len(ids)) as s:
+            for r, w in enumerate(wheres):
+                if w is None:
+                    vsets.append(base)
+                    kept[:] = True
+                    continue
+                keep = np.asarray(w.evaluate(frame, ""), dtype=bool) & ~rejects[r]
+                kept |= keep
+                vsets.append(VSet.from_dense_ids(
+                    seed0.vertex_type, len(base.mask), ids[keep]))
+            s.set_metadata(rows_out=int(np.count_nonzero(kept)))
+    else:
+        vsets = [base] * len(stmts)
+    for r, s in enumerate(stmts):
+        if s.seed.accum_where:
+            vsets[r] = VSet(s.seed.vertex_type, vsets[r].mask
+                            & _accum_seed_mask(accums_list[r], topo, s.seed))
+    return vsets
+
+
+def _run_hop_batched(eng, frontiers, hops, accums_list, topo, counters, pool,
+                     epoch, deadline, accum_outs):
+    """One shared-scan hop and its stacked ACCUM."""
+    from repro.core.primitives import edge_scan_batched
+
+    with _hop_span(hops, frontiers) as s:
+        scan = edge_scan_batched(
+            eng._topo(epoch), eng.cache, frontiers, hops[0].edge_type,
+            hops[0].direction, [plan_hop(h) for h in hops],
+            prefetcher=eng.prefetcher, counters=counters, pool=pool,
+            deadline=deadline,
+        )
+        if hops[0].accum is not None:
+            with span("accum", rows=len(scan.u)):
+                _apply_accum_batched(accums_list, topo, hops, scan, accum_outs)
+        s.set_metadata(rows_out=len(scan.u))
+    return scan
 
 
 def _apply_accum_batched(accums_list, topo, hops, scan, accum_outs):
@@ -839,8 +887,6 @@ def _apply_accum_batched(accums_list, topo, hops, scan, accum_outs):
     slice — same ``np.<op>.at`` path as solo.
     """
     a0 = hops[0].accum
-    if a0 is None:    # riders share the template's accum shape (batchable)
-        return
     if a0.target == "v":
         tgt_type, tgt_ids = scan.v_type, scan.v
     else:

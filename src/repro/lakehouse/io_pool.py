@@ -6,7 +6,7 @@ IDM and subsequent edge lists".  The pool is a thin, instrumented wrapper
 around ``concurrent.futures.ThreadPoolExecutor`` with:
 
 - bounded in-flight depth (models the store's parallel stream budget),
-- per-task timing so benchmarks can report overlap efficiency,
+- a task counter (``stats["tasks"]``),
 - a ``map_pipelined`` helper that runs ``fetch`` on I/O threads and ``compute``
   on the caller thread, keeping ``depth`` fetches in flight ahead of compute —
   the exact producer/consumer structure of the startup loader.
@@ -20,7 +20,6 @@ around ``concurrent.futures.ThreadPoolExecutor`` with:
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import FIRST_COMPLETED, Future, ThreadPoolExecutor, wait
 from typing import Callable, Iterable, Iterator, Sequence, TypeVar
 
@@ -34,8 +33,8 @@ class IOPool:
         self._pool = ThreadPoolExecutor(max_workers=n_threads, thread_name_prefix="io")
         self._sem = threading.Semaphore(max_in_flight)
         self._lock = threading.Lock()
-        self.stats = {"tasks": 0, "io_seconds": 0.0, "backup_fetches": 0,
-                      "backup_wins": 0, "hedged_errors": 0}
+        self.stats = {"tasks": 0, "backup_fetches": 0, "backup_wins": 0,
+                      "hedged_errors": 0}
 
     def close(self) -> None:
         self._pool.shutdown(wait=True)
@@ -52,14 +51,11 @@ class IOPool:
         self._sem.acquire()
 
         def _run():
-            t0 = time.perf_counter()
             try:
                 return fn(*args, **kwargs)
             finally:
-                dt = time.perf_counter() - t0
                 with self._lock:
                     self.stats["tasks"] += 1
-                    self.stats["io_seconds"] += dt
                 self._sem.release()
 
         try:

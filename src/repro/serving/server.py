@@ -106,6 +106,11 @@ from repro.errors import (  # noqa: F401
     TenantQuotaExceededError,
 )
 from repro.gsql.session import GraphSession
+from repro.tracing import name_os_thread, span
+
+# a scheduler wake-up later than this past its timeout counts as a stall:
+# something (as a rule the interpreter lock) kept the server from running
+STALL_S = 0.010
 
 
 @dataclasses.dataclass
@@ -196,8 +201,8 @@ class QueryServer:
         self._window_s = max(0.0, float(window)) / 1000.0
         self._q: queue.Queue = queue.Queue(maxsize=self.config.max_queue)
         # scheduler -> workers: ((priority, seq), unit); unit is
-        # ("lookup", req) | ("single", req) | ("batch", [reqs]) | None
-        # (worker shutdown)
+        # (kind, payload, dispatched at) with ("lookup", req) | ("single",
+        # req) | ("batch", [reqs]), or None (worker shutdown)
         self._exec_q: queue.PriorityQueue = queue.PriorityQueue()
         self._seq = 0
         self._results: dict[int, QueryResult] = {}
@@ -218,16 +223,20 @@ class QueryServer:
             "lookup_requests": 0,    # served by the point-lookup fast path
             "route_green": 0,        # ... of which needed no lake columns
             "route_yellow": 0,       # ... of which paid a column fetch path
+            "stall_s": 0.0,          # scheduler lateness past STALL_S, summed
+            "max_stall_s": 0.0,      # ... and the largest one
         }
         # wire-surface dispatch counters (handle()): per-route hits + errors,
         # surfaced by health() under "routes"
         self.route_stats = {"/vertex": 0, "/neighbors": 0, "/query": 0,
                             "/lookup": 0, "/health": 0, "errors": 0}
-        self._scheduler = threading.Thread(target=self._schedule, daemon=True)
+        self._scheduler = threading.Thread(target=self._schedule, daemon=True,
+                                           name="serve-scheduler")
         self._scheduler.start()
         self._workers = [
-            threading.Thread(target=self._worker, daemon=True)
-            for _ in range(self.config.n_workers)
+            threading.Thread(target=self._worker, daemon=True,
+                             name=f"serve-worker-{i}")
+            for i in range(self.config.n_workers)
         ]
         for w in self._workers:
             w.start()
@@ -244,7 +253,8 @@ class QueryServer:
             interval = perf_flags.value("refresh", 30.0)
         if interval is not None and interval > 0 and hasattr(self.engine, "advance"):
             self._refresher = threading.Thread(
-                target=self._refresh_loop, args=(float(interval),), daemon=True
+                target=self._refresh_loop, args=(float(interval),), daemon=True,
+                name="serve-refresh",
             )
             self._refresher.start()
 
@@ -343,6 +353,7 @@ class QueryServer:
         (results stamped ``degraded``), and after ``breaker_cooldown_s``
         one half-open probe advance decides re-open vs close.
         """
+        name_os_thread()
         cfg = self.config
         wait_s = interval_s
         while not self._refresh_stop.wait(wait_s):
@@ -352,7 +363,8 @@ class QueryServer:
                     self._breaker_state = "half_open"
                     self.refresh_stats["half_open_probes"] += 1
             try:
-                report = self.engine.advance()
+                with span("serve.refresh"):
+                    report = self.engine.advance()
             except Exception as e:  # queries stay on the pinned epoch
                 with self._lock:
                     self.refresh_stats["errors"] += 1
@@ -528,11 +540,18 @@ class QueryServer:
                 and req.name not in self.query_fns
                 and self.session.is_installed(req.name))
 
-    def _dispatch(self, priority: int, unit) -> None:
+    def _dispatch(self, priority: int, kind: str, payload) -> None:
         with self._lock:
             seq = self._seq
             self._seq += 1
-        self._exec_q.put(((priority, seq), unit))
+        self._exec_q.put(((priority, seq), (kind, payload, time.perf_counter())))
+
+    def _note_stall(self, late_s: float) -> None:
+        with self._lock:
+            self.stats["stall_s"] += late_s
+            self.stats["max_stall_s"] = max(self.stats["max_stall_s"], late_s)
+        with span("serve.stall", late_s=late_s):
+            pass
 
     def _schedule(self) -> None:
         """Drain submissions into dispatch units.
@@ -545,6 +564,7 @@ class QueryServer:
         Everything else dispatches immediately.  Buckets never cross
         priority lanes; a flushed unit keeps its lane's priority.
         """
+        name_os_thread()
         buckets: dict[tuple, list[_Request]] = {}
         flush_at: dict[tuple, float] = {}
         last_sweep = time.monotonic()
@@ -561,11 +581,14 @@ class QueryServer:
                 req = self._q.get(timeout=wait) if not closing else self._q.get_nowait()
             except queue.Empty:
                 req = False   # timeout (None is the shutdown sentinel)
+                late = time.monotonic() - (now + wait)
+                if not closing and late > STALL_S:
+                    self._note_stall(late)
             if req is None:
                 closing = True
             elif req is not False:
                 if self._lookup_fast(req):
-                    self._dispatch(req.priority, ("lookup", req))
+                    self._dispatch(req.priority, "lookup", req)
                 elif self._batchable(req):
                     key = (req.name, req.priority)
                     bucket = buckets.setdefault(key, [])
@@ -573,13 +596,13 @@ class QueryServer:
                         flush_at[key] = time.monotonic() + self._window_s
                     bucket.append(req)
                     if len(bucket) >= self.config.max_batch_riders:
-                        self._dispatch(req.priority, ("batch", bucket))
+                        self._dispatch(req.priority, "batch", bucket)
                         del buckets[key], flush_at[key]
                 else:
-                    self._dispatch(req.priority, ("single", req))
+                    self._dispatch(req.priority, "single", req)
             now = time.monotonic()
             for key in [k for k, t in flush_at.items() if t <= now or closing]:
-                self._dispatch(key[1], ("batch", buckets.pop(key)))
+                self._dispatch(key[1], "batch", buckets.pop(key))
                 del flush_at[key]
             if now - last_sweep >= 1.0:
                 last_sweep = now
@@ -740,19 +763,26 @@ class QueryServer:
                            degraded=deg)
 
     def _worker(self) -> None:
+        name_os_thread()
         while True:
             _, unit = self._exec_q.get()
             if unit is None:
                 return
-            kind, payload = unit
-            if kind == "lookup":
-                self._run_lookup(payload)
-            elif kind == "single":
-                self._run_single(payload)
-            elif len(payload) == 1:   # one-rider bucket: the solo path
-                self._run_single(payload[0])
-            else:
-                self._run_shared(payload)
+            kind, payload, t_dispatch = unit
+            reqs = payload if kind == "batch" else [payload]
+            if kind == "batch" and len(reqs) == 1:   # one-rider bucket: solo
+                kind = "single"
+            t_start = time.perf_counter()
+            with span("serve.unit", kind=kind, template=reqs[0].name,
+                      riders=len(reqs), rids=";".join(str(r.rid) for r in reqs),
+                      rider_wait_s=len(reqs) * (t_start - t_dispatch),
+                      batch_wait_s=t_dispatch - reqs[0].t_submit):
+                if kind == "lookup":
+                    self._run_lookup(reqs[0])
+                elif kind == "single":
+                    self._run_single(reqs[0])
+                else:
+                    self._run_shared(reqs)
 
 
 def _wire_id(raw: str):
