@@ -9,9 +9,10 @@ All five run on the *topology only* (no property access), consuming the
   (edge-block, output-block) pair;
 - PageRank's inner reduction is the CSR offset-range segment sum
   (``kops.csr_segment_sum``), fed by the reverse-CSR index — no per-edge
-  destination ids at all.  Its 1-D rank column takes the searchsorted
-  reference path; the Pallas offset-range kernel serves the 2-D
-  (multi-channel) form of the same op;
+  destination ids are stored or uploaded.  For its 1-D rank column the op
+  derives each arc's segment id from the offsets in one linear pass on the
+  device; the Pallas offset-range kernel serves the 2-D (multi-channel)
+  form of the same op;
 - BFS dispatches adaptively per level, exactly like EdgeScan: small
   frontiers expand through CSR adjacency ranges, large frontiers fall back
   to the edge-centric masked scan.

@@ -2,10 +2,12 @@
 selectivities and directions, adaptive dispatch, CSR lake materialization
 round-trip, incremental invalidation, and the offset-range segment kernel."""
 
+import re
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
-
-import jax.numpy as jnp
 
 from repro.core.csr import CSRIndex
 from repro.core.engine import GraphLakeEngine
@@ -273,3 +275,64 @@ def test_csr_segment_sum_1d(g500):
     vals = jnp.ones(csr.n_edges, dtype=jnp.float32)
     got = kops.csr_segment_sum(vals, jnp.asarray(csr.rev_indptr), n)
     np.testing.assert_allclose(np.asarray(got), csr.degrees("in").astype(np.float32))
+
+
+def _indptr_case(name: str):
+    """(indptr, e) for one shape of CSR the linear id derivation must get right."""
+    rng = np.random.default_rng(len(name))
+    if name == "empty_segments":
+        deg = rng.integers(0, 4, size=60) * (rng.random(60) < 0.4)
+        return np.concatenate([[0], np.cumsum(deg)]), int(deg.sum())
+    if name == "one_giant_segment":
+        return np.array([0] * 8 + [1000] * 9), 1000
+    if name == "n_is_1":
+        return np.array([0, 37]), 37
+    if name == "e_is_1":
+        return np.array([0, 0, 0, 0, 1, 1, 1, 1, 1]), 1
+    if name == "e_below_n":
+        return np.concatenate([[0], np.sort(rng.integers(0, 21, size=100))]), 20
+    if name == "arcs_past_last_offset":
+        return np.concatenate([[0], np.sort(rng.integers(0, 61, size=9)), [60]]), 80
+    assert name == "arcs_before_first_offset"
+    return np.concatenate([[5], np.sort(rng.integers(5, 40, size=9)), [40]]), 40
+
+
+_INDPTR_CASES = ["empty_segments", "one_giant_segment", "n_is_1", "e_is_1", "e_below_n",
+                 "arcs_past_last_offset", "arcs_before_first_offset"]
+
+
+@pytest.mark.parametrize("case", _INDPTR_CASES)
+def test_csr_segment_ids_match_searchsorted(case):
+    indptr, e = _indptr_case(case)
+    got = kops._csr_segment_ids(jnp.asarray(indptr, jnp.int32), e)
+    want = np.searchsorted(indptr, np.arange(e), side="right") - 1
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("d", [None, 4])
+@pytest.mark.parametrize("case", _INDPTR_CASES)
+def test_csr_segment_sum_off_pallas_matches_ref(case, d, monkeypatch):
+    """The non-Pallas path of ``csr_segment_sum`` (1-D always, 2-D off the
+    chip) against the searchsorted reference."""
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    assert not kops.use_pallas()
+    indptr, e = _indptr_case(case)
+    n = len(indptr) - 1
+    rng = np.random.default_rng(e + n)
+    shape = (e,) if d is None else (e, d)
+    values = jnp.asarray(rng.standard_normal(shape), dtype=jnp.float32)
+    indptr = jnp.asarray(indptr, jnp.int32)
+    got = kops.csr_segment_sum(values, indptr, n)
+    want = ref.csr_segment_sum(values, indptr, n)
+    assert got.shape == want.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-4)
+
+
+def test_csr_segment_sum_1d_compiles_without_a_loop():
+    """The 1-D path derives its segment ids in one pass: a binary search,
+    which compiles to a ``while`` loop over every arc, must not come back."""
+    e, n = 2**20, 2**14
+    text = jax.jit(lambda v, p: kops.csr_segment_sum(v, p, n)).lower(
+        jax.ShapeDtypeStruct((e,), jnp.float32),
+        jax.ShapeDtypeStruct((n + 1,), jnp.int32)).compile().as_text()
+    assert not re.search(r" while\(", text)
