@@ -8,8 +8,9 @@ For each seed it makes the cell's data, takes the requests a run of that
 seed sends (open-loop cells) or the jobs it runs (job cells), and reads each
 number the cell compares, as the control would give it:
 
-- BI answers from the reference with float32 accumulators, counted against
-  the float64 reference (``answers_wrong_or_missing``);
+- open-loop answers from the traffic's reference (``refs/<reference>.py``,
+  see ``drivers/open_loop.py``) with float32 accumulators, counted against
+  the same reference in float64 (``answers_wrong_or_missing``);
 - ranks from the power iteration in bfloat16, against float64
   (``rank_max_rel_err``), and, for scale, the same in float32.
 
@@ -44,22 +45,23 @@ def _analytics_reading(tables, schema, a: dict, dtype: str) -> float:
 
 
 def readings(cell, seed: int, seconds: float, load_module) -> dict:
-    gen = load_module(CHIP_DIR / "generators" / f"{cell.config['generator']}.py", "gen")
+    gen = load_module(cell.chip_dir / "generators" / f"{cell.config['generator']}.py",
+                      "gen")
     tables, schema = gen.generate(cell.config, seed), gen.graph_schema()
     traffic = cell.traffic
     out = {"seed": seed}
     if traffic["driver"] == "open_loop":
-        from refs.ldbc_queries import LDBCReference, same_answer
-
-        driver = load_module(CHIP_DIR / "drivers" / "open_loop.py", "driver")
+        driver = load_module(cell.chip_dir / "drivers" / "open_loop.py", "driver")
+        ref_module = driver.reference_module(cell.chip_dir, traffic)
         plan = driver.schedule(traffic, seed, seconds)
-        f64, f32 = LDBCReference(tables), LDBCReference(tables, np.float32)
+        f64 = ref_module.reference(tables)
+        f32 = ref_module.reference(tables, np.float32)
         differs = {}
         for _, name, params in plan:
             key = (name, tuple(sorted(params.items())))
             if name in traffic["queries"] and key not in differs:
-                differs[key] = not same_answer(f64.answer(name, params),
-                                               f32.answer(name, params))
+                differs[key] = not ref_module.same_answer(f64.answer(name, params),
+                                                          f32.answer(name, params))
         out["answers_wrong_or_missing"] = sum(
             differs.get((n, tuple(sorted(p.items()))), False) for _, n, p in plan)
         out["requests"] = len(plan)

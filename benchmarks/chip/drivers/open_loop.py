@@ -14,8 +14,10 @@ counts as infinitely late.  The window closes when the last request is
 answered, or a minute after the last was due.
 
 Check: every answer due in the window is compared with the plain reference
-(``refs/``) once the window has closed: BI answers exactly, ranks within the
-traffic file's limit of the float64 power iteration.
+once the window has closed: a template's answer exactly with
+``refs/<reference>.py``, the module the traffic file's ``reference`` key names
+(see ``reference_module``), and ranks within the traffic file's limit of the
+float64 power iteration.
 """
 
 from __future__ import annotations
@@ -25,11 +27,25 @@ import itertools
 import math
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 
 LATE_AFTER_CLOSE_S = 60.0
 WARM_PASSES = 5
+DEFAULT_REFERENCE = "ldbc_queries"
+
+
+def reference_module(chip_dir: Path, traffic: dict):
+    """``refs/<name>.py`` for the traffic file's ``reference`` key
+    (``DEFAULT_REFERENCE`` where it has none).  The module gives
+    ``reference(tables, dtype=np.float64)``, whose ``.answer(name, params)``
+    is a template's answer, ``program_answer(result, raw_of_dense, want)``,
+    the program's result in that form, and ``same_answer(a, b)``."""
+    from harness import load_module
+
+    name = traffic.get("reference", DEFAULT_REFERENCE)
+    return load_module(chip_dir / "refs" / f"{name}.py", f"chipbench_ref_{name}")
 
 
 def param_combos(pool) -> list[dict]:
@@ -160,11 +176,10 @@ def send(server, ctx, plan: list, installed: set) -> tuple[list, list]:
 
 
 def _verify(records: list, plan: list, tables: dict, schema, raw_of_dense: dict,
-            traffic: dict, installed: set) -> tuple[dict, list]:
-    from refs.ldbc_queries import LDBCReference, program_answer, same_answer
+            traffic: dict, installed: set, ref_module) -> tuple[dict, list]:
     from refs.pagerank import max_rel_err, pagerank_tables
 
-    ref = LDBCReference(tables)
+    ref = ref_module.reference(tables)
     answers: dict = {}
     wrong = []
     rank_err = 0.0
@@ -184,7 +199,8 @@ def _verify(records: list, plan: list, tables: dict, schema, raw_of_dense: dict,
                     a["damping"], a["supersteps"])
         want = answers[key]
         if name in installed:
-            if not same_answer(want, program_answer(rec["answer"], raw_of_dense, want)):
+            got = ref_module.program_answer(rec["answer"], raw_of_dense, want)
+            if not ref_module.same_answer(want, got):
                 wrong.append((i, name, params, "differs from the reference"))
         else:
             vt = schema.edge_types[traffic["analytics"][name]["edge_type"]].src_type
@@ -265,6 +281,7 @@ def latency_ms(records: list, q: float) -> float:
 
 def run(ctx) -> dict:
     traffic = ctx.traffic
+    ref_module = reference_module(ctx.cell.chip_dir, traffic)
     s = start(ctx)
     try:
         plan = schedule(traffic, ctx.seed, ctx.seconds)
@@ -280,7 +297,7 @@ def run(ctx) -> dict:
         s.close()
 
     checks, wrong = _verify(records, plan, s.tables, s.schema, raw_of_dense, traffic,
-                            s.installed)
+                            s.installed, ref_module)
     for i, name, params, why in wrong[:5]:
         ctx.log(f"request {i} {name} {params}: {why}")
     ok = [r for r in records if r and r["ok"]]

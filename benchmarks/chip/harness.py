@@ -7,11 +7,22 @@ lives in a file of its own, found by the name ``BENCHMARK.json`` gives it:
   ``generator`` key names ``generators/<generator>.py``;
 - ``traffic/<traffic>.json``: the mix; its ``driver`` key names
   ``drivers/<driver>.py``, which runs set-up, the window and the check;
+- ``refs/<reference>.py``: the plain reference of an open-loop traffic's
+  templates, named by the traffic file's ``reference`` key (``ldbc_queries``
+  where it has none; what it gives: ``drivers/open_loop.py``,
+  ``reference_module``);
 - ``layers/<metric>.py``: one reader per per-layer metric.
 
 A driver reports raw observations; this module picks the cell's end-to-end
 metrics (``--trace 0``) or per-layer metrics (``--trace 1``) from them and
 prints the result line.  Nothing here is specific to one cell.
+
+A cell is added with new files and additions to ``BENCHMARK.json`` alone: its
+entries under ``configs``, ``workloads`` and the metrics, and its name
+appended to the ``workloads`` list of each metric it reports.  Its CPU twin,
+which the tests run, is ``tests/tiny/configs/<config>.json`` (required) and
+``tests/tiny/traffic/<traffic>.json`` (optional): top-level keys that replace
+those of the configuration and traffic files.  Only the tests read them.
 """
 
 from __future__ import annotations
@@ -228,6 +239,15 @@ def _fmt(v) -> str:
     return repr(v) if isinstance(v, float) else str(v)
 
 
+def breakdown(summary: dict, program: dict) -> dict:
+    """The result line's ``breakdown``: the device operations that took most
+    time, and the idle gaps named by the program's spans
+    (``program_trace.reduce``) where the window holds any, else by the
+    benchmark's own (``trace_reduce``)."""
+    gaps = program["idle_gaps"] if program["program"] else summary["idle_gaps"]
+    return {"device_ops": summary["device_ops"], "idle_gaps": gaps}
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              root: Path, t_start: float, on_chip: bool = True,
              log: Callable[[str], None] = None) -> dict:
@@ -288,8 +308,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     out["metrics"] = metrics
     out["device"] = device
     if summary is not None:
-        out["breakdown"] = {"device_ops": summary["device_ops"],
-                            "idle_gaps": summary["idle_gaps"]}
+        import program_trace
+
+        program = program_trace.reduce(
+            program_trace.load(program_trace.newest_trace(ctx.trace_dir)), ctx.chips)
+        out["breakdown"] = breakdown(summary, program)
     out["checks"] = checks
     for name, c in checks.items():
         log(f"check {name}: {_fmt(c['value'])} (limit {_fmt(c['limit'])})")

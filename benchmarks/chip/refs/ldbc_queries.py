@@ -136,6 +136,11 @@ class LDBCReference:
         return getattr(self, template)(**params)
 
 
+def reference(tables: dict, dtype=np.float64) -> LDBCReference:
+    """The open-loop driver's entry: the reference over ``tables``."""
+    return LDBCReference(tables, dtype)
+
+
 def program_answer(result, raw_of_dense: dict, ref: dict) -> dict:
     """The program's ``QueryResult`` in the reference's raw-id form; the
     vertex type of each accumulator is the reference's for that name."""

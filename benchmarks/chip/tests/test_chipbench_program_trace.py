@@ -14,7 +14,7 @@ sys.path.insert(0, str(CHIP))
 
 import program_trace  # noqa: E402
 import trace_reduce  # noqa: E402
-from harness import SPAN_PREFIX, WORK_REL, load_module  # noqa: E402
+from harness import SPAN_PREFIX, WORK_REL, breakdown, load_module  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 W0, W1 = "1 serve-worker-0", "2 serve-worker-1"
@@ -214,3 +214,32 @@ def test_recorded_pagerank_run_scopes_its_busy_time(monkeypatch):
     monkeypatch.setattr(program_trace, "for_run", lambda obs, root: ev)
     mb = _layer("h2d_mb_per_superstep").read({"trace": {"window_s": 24.0}, "supersteps": 2})
     assert mb == pytest.approx((63539332 * 4 + 1244954 * 4 + 1244953 * 4) / 2 / 1e6)
+
+
+# -- the result line's breakdown ----------------------------------------------------
+
+def _benchmark_form(ev: dict) -> dict:
+    """Recorded events as ``trace_reduce`` reads a trace: benchmark spans only."""
+    return {"spans": [["window", *ev["window"]]] + ev["bench_spans"],
+            "devices": [[op[:3] for op in ops] for ops in ev["devices"]]}
+
+
+@pytest.mark.parametrize("name", ["bi", "pagerank"])
+def test_breakdown_names_idle_gaps_by_program_spans(name):
+    ev = _recorded(name)
+    summary = trace_reduce.reduce(_benchmark_form(ev), 1)
+    program = program_trace.reduce(ev)
+    b = breakdown(summary, program)
+    assert list(b) == ["device_ops", "idle_gaps"]
+    assert b["device_ops"] == summary["device_ops"]
+    assert b["idle_gaps"] == program["idle_gaps"] != summary["idle_gaps"]
+    assert sum(v for _, v in b["idle_gaps"]) == pytest.approx(
+        sum(v for _, v in summary["idle_gaps"]))
+    assert b["idle_gaps"][0][0] in program["program"]
+
+
+def test_breakdown_without_program_spans_names_gaps_by_benchmark_spans():
+    summary = trace_reduce.reduce(json.loads((DATA / "pagerank_trace_events.json").read_text()), 1)
+    b = breakdown(summary, program_trace.reduce(_recorded_pagerank()))
+    assert b == {"device_ops": summary["device_ops"], "idle_gaps": summary["idle_gaps"]}
+    assert [n for n, _ in b["idle_gaps"]] == ["job.pagerank"]

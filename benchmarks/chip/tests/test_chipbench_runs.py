@@ -1,8 +1,12 @@
 """Whole runs of the chip benchmark's harness on the CPU, at tiny sizes.
 
-A throwaway benchmark is made in a temporary checkout from new files only (a
-configuration, a traffic mix and a ``BENCHMARK.json`` entry per cell) beside
-a copy of the harness's code, which no test edits.  The runs skip the
+Every cell of ``BENCHMARK.json`` has a CPU twin: a throwaway checkout holds a
+copy of the benchmark in which each configuration file is merged with
+``tiny/configs/<config>.json`` and each traffic file with
+``tiny/traffic/<traffic>.json`` where there is one (top-level keys replace).
+Cells keep their names, so every metric's ``workloads`` list holds as it is,
+and the tests take the cells from ``BENCHMARK.json``: a cell added with new
+files and appended names is run with no edit here.  The runs skip the
 harness's look for a chip; everything else is a real run.  The fault tests
 break the timed path underneath and must see ``correct`` come out false.
 """
@@ -22,45 +26,59 @@ import pytest
 
 CHIP = Path(__file__).resolve().parents[1]
 REPO = CHIP.parents[1]
+TINY = Path(__file__).resolve().parent / "tiny"
 sys.path.insert(0, str(CHIP))
 
-from harness import run_cell  # noqa: E402
+import control  # noqa: E402
+from harness import CHIP_REL, find_cell, load_module, run_cell  # noqa: E402
+
+SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
 
 
-def _tiny_root(tmp: Path) -> Path:
+def _traffic(workload: dict) -> dict:
+    return json.loads((CHIP / "traffic" / f"{workload['traffic']}.json").read_text())
+
+
+def _cells(keep=lambda traffic: True) -> list:
+    return [w["name"] for w in SPEC["workloads"] if keep(_traffic(w))]
+
+
+CELLS = _cells()
+OPEN_LOOP_CELLS = _cells(lambda t: t["driver"] == "open_loop")
+JOB_CELLS = _cells(lambda t: t["driver"] == "jobs")
+# cells whose window runs a PageRank: a job cell, or analytics beside serving
+PAGERANK_CELLS = _cells(lambda t: t.get("algorithm") == "pagerank" or any(
+    a["algorithm"] == "pagerank" for a in t.get("analytics", {}).values()))
+
+
+def _merge(path: Path, overrides: Path, into: Path) -> None:
+    body = json.loads(path.read_text())
+    body.update(json.loads(overrides.read_text()))
+    into.parent.mkdir(parents=True, exist_ok=True)
+    into.write_text(json.dumps(body))
+
+
+def _tiny_root(tmp: Path, src: Path = REPO) -> Path:
+    """A checkout under ``tmp`` holding the CPU twin of every cell of the
+    benchmark at ``src`` (a checkout's root); ``src`` is left as it was."""
     root = tmp / "checkout"
-    shutil.copytree(CHIP, root / "benchmarks" / "chip",
+    shutil.copytree(src / CHIP_REL, root / CHIP_REL,
                     ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    chip = root / "benchmarks" / "chip"
-    spec = json.loads((REPO / "BENCHMARK.json").read_text())
-
-    ldbc = json.loads((chip / "configs" / "ldbc-snb-sf1.json").read_text())
-    ldbc.update(name="tiny-ldbc", persons=200, comments=20000, tags=160,
-                row_group_rows=2048)
-    g500 = json.loads((chip / "configs" / "graph500-22.json").read_text())
-    g500.update(name="tiny-g500", scale=10, row_group_rows=4096)
-    bi = json.loads((chip / "traffic" / "bi.json").read_text())
-    bi["rate_per_s"] = 20.0
-    bi["mix"] = {"bi1": 4, "bi2": 4, "bi3": 4, "bi4": 4, "bi5": 4, "pagerank_knows": 1}
-    pr = json.loads((chip / "traffic" / "pagerank.json").read_text())
-    for name, body in (("configs/tiny-ldbc.json", ldbc), ("configs/tiny-g500.json", g500),
-                       ("traffic/tiny-bi.json", bi), ("traffic/tiny-pagerank.json", pr)):
-        (chip / name).write_text(json.dumps(body))
-
-    rename = {"ldbc-sf1.bi": "tiny.bi", "graph500-22.pagerank": "tiny.pagerank"}
-    spec["configs"] = [
-        {"name": "tiny-ldbc", "source": "test", "file": "benchmarks/chip/configs/tiny-ldbc.json",
-         "reduced": [], "why": "test"},
-        {"name": "tiny-g500", "source": "test", "file": "benchmarks/chip/configs/tiny-g500.json",
-         "reduced": [], "why": "test"}]
-    spec["workloads"] = [
-        {"name": "tiny.bi", "config": "tiny-ldbc", "traffic": "tiny-bi", "chips": 1, "why": "test"},
-        {"name": "tiny.pagerank", "config": "tiny-g500", "traffic": "tiny-pagerank",
-         "chips": 1, "why": "test"}]
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        if "workloads" in m:
-            m["workloads"] = [rename[w] for w in m["workloads"]]
-    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    shutil.copy(src / "BENCHMARK.json", root)
+    spec = json.loads((src / "BENCHMARK.json").read_text())
+    tiny = src / CHIP_REL / TINY.relative_to(CHIP)
+    for c in spec["configs"]:
+        overrides = tiny / "configs" / f"{c['name']}.json"
+        if not overrides.is_file():
+            pytest.fail(f"configuration {c['name']!r} has no CPU twin: add "
+                        f"{overrides.relative_to(src)}, the keys of {c['file']} that make "
+                        f"it small enough for the CPU", pytrace=False)
+        _merge(src / c["file"], overrides, root / c["file"])
+    for name in {w["traffic"] for w in spec["workloads"]}:
+        overrides = tiny / "traffic" / f"{name}.json"
+        if overrides.is_file():
+            rel = CHIP_REL / "traffic" / f"{name}.json"
+            _merge(src / rel, overrides, root / rel)
     return root
 
 
@@ -79,9 +97,7 @@ def _line_contract(out: dict, spec: dict, workload: str, trace: bool):
     assert keys[:3] == ["correct", "attempted", "failed"] and keys[-1] == "checks"
     assert {"metrics", "device"} <= set(keys)
     json.loads(json.dumps(out))
-    kind = "per_layer" if trace else "end_to_end"
-    want = {m["name"] for m in spec[kind]
-            if "workloads" not in m or workload in m["workloads"]}
+    want = _applies(spec, "per_layer" if trace else "end_to_end", workload)
     if trace:
         assert set(out["metrics"]) <= want
         assert {"busy_s", "window_s"} <= set(out["device"])
@@ -94,7 +110,12 @@ def _line_contract(out: dict, spec: dict, workload: str, trace: bool):
         assert set(c) == {"value", "limit"}
 
 
-@pytest.mark.parametrize("workload", ["tiny.bi", "tiny.pagerank"])
+def _applies(spec: dict, kind: str, workload: str) -> set:
+    return {m["name"] for m in spec[kind]
+            if "workloads" not in m or workload in m["workloads"]}
+
+
+@pytest.mark.parametrize("workload", CELLS)
 def test_throwaway_cell_runs_correct(root, workload):
     spec = json.loads((root / "BENCHMARK.json").read_text())
     out = _run(root, workload)
@@ -103,15 +124,18 @@ def test_throwaway_cell_runs_correct(root, workload):
     _line_contract(out, spec, workload, trace=False)
 
 
-def test_traced_run_reports_per_layer_metrics(root):
+@pytest.mark.parametrize("workload", CELLS)
+def test_traced_run_reports_per_layer_metrics(root, workload):
     spec = json.loads((root / "BENCHMARK.json").read_text())
-    out = _run(root, "tiny.bi", trace=True)
+    out = _run(root, workload, trace=True)
     assert out["correct"], out["checks"]
-    _line_contract(out, spec, "tiny.bi", trace=True)
-    assert {"queue_wait_ms", "service_p50_ms", "edge_list_build_s"} <= set(out["metrics"])
+    _line_contract(out, spec, workload, trace=True)
+    program_read = {"queue_wait_ms", "service_p50_ms", "edge_list_build_s"}
+    assert program_read & _applies(spec, "per_layer", workload) <= set(out["metrics"])
 
 
-def test_answer_altered_is_not_correct(root, monkeypatch):
+@pytest.mark.parametrize("workload", OPEN_LOOP_CELLS)
+def test_answer_altered_is_not_correct(root, monkeypatch, workload):
     import repro.gsql.session as session
 
     def altered(fn):
@@ -125,12 +149,12 @@ def test_answer_altered_is_not_correct(root, monkeypatch):
     monkeypatch.setattr(session, "execute_compiled", altered(session.execute_compiled))
     monkeypatch.setattr(session, "execute_compiled_batch",
                         altered(session.execute_compiled_batch))
-    out = _run(root, "tiny.bi", seed=5)
+    out = _run(root, workload, seed=5)
     assert not out["correct"]
     assert out["checks"]["answers_wrong_or_missing"]["value"] > 0
 
 
-@pytest.mark.parametrize("workload", ["tiny.bi", "tiny.pagerank"])
+@pytest.mark.parametrize("workload", PAGERANK_CELLS)
 def test_step_returning_its_state_is_not_correct(root, monkeypatch, workload):
     from repro.core import algorithms
 
@@ -143,7 +167,8 @@ def test_step_returning_its_state_is_not_correct(root, monkeypatch, workload):
         out["checks"]["rank_max_rel_err"]["limit"]
 
 
-def test_ranks_altered_is_not_correct(root, monkeypatch):
+@pytest.mark.parametrize("workload", JOB_CELLS)
+def test_ranks_altered_is_not_correct(root, monkeypatch, workload):
     from repro.core import algorithms
 
     real = algorithms.pagerank
@@ -154,8 +179,88 @@ def test_ranks_altered_is_not_correct(root, monkeypatch):
         return r
 
     monkeypatch.setattr(algorithms, "pagerank", altered)
-    out = _run(root, "tiny.pagerank", seed=7)
+    out = _run(root, workload, seed=7)
     assert not out["correct"]
+
+
+# -- a cell from new files alone ---------------------------------------------------
+
+def _add_cell_from_new_files(src: Path) -> None:
+    """What a later change adds for a cell: a configuration, its CPU twin and
+    a traffic mix as new files, entries appended to ``BENCHMARK.json``, and
+    the cell's name appended to the lists of the metrics it reports."""
+    chip = src / CHIP_REL
+    shutil.copy(chip / "configs" / "ldbc-snb-sf1.json", chip / "configs" / "extra-ldbc.json")
+    shutil.copy(chip / "tests" / "tiny" / "configs" / "ldbc-snb-sf1.json",
+                chip / "tests" / "tiny" / "configs" / "extra-ldbc.json")
+    traffic = json.loads((chip / "traffic" / "bi.json").read_text())
+    (chip / "traffic" / "extra-bi.json").write_text(
+        json.dumps(dict(traffic, reference="ldbc_queries")))
+    spec = json.loads((src / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "extra-ldbc", "source": "test",
+                            "file": "benchmarks/chip/configs/extra-ldbc.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "extra.bi", "config": "extra-ldbc",
+                              "traffic": "extra-bi", "chips": 1, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m["name"] in ("query_p50_ms", "queue_wait_ms"):
+            m["workloads"].append("extra.bi")
+    (src / "BENCHMARK.json").write_text(json.dumps(spec, indent=2))
+
+
+def _only_appended(old, new) -> bool:
+    """``new`` is ``old`` with entries appended to its lists, and no other change."""
+    if isinstance(old, dict):
+        return (isinstance(new, dict) and old.keys() == new.keys()
+                and all(_only_appended(old[k], new[k]) for k in old))
+    if isinstance(old, list):
+        return (isinstance(new, list) and len(new) >= len(old)
+                and all(_only_appended(a, b) for a, b in zip(old, new)))
+    return old == new
+
+
+def _files(src: Path) -> dict:
+    return {p.relative_to(src): p.read_bytes() for p in sorted(src.rglob("*"))
+            if p.is_file() and "__pycache__" not in p.parts}
+
+
+def _later_checkout(tmp: Path) -> Path:
+    src = tmp / "later"
+    shutil.copytree(CHIP, src / CHIP_REL, ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(REPO / "BENCHMARK.json", src)
+    return src
+
+
+def test_a_cell_from_new_files_alone_runs_correct_with_its_control(tmp_path):
+    src = _later_checkout(tmp_path)
+    before = _files(src)
+    _add_cell_from_new_files(src)
+    root = _tiny_root(tmp_path, src)
+    after = _files(src)
+    spec_rel = Path("BENCHMARK.json")
+    assert all(after[rel] == data for rel, data in before.items() if rel != spec_rel)
+    assert _only_appended(json.loads(before[spec_rel]), json.loads(after[spec_rel]))
+
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    seed = 2**31 + 23
+    out = _run(root, "extra.bi", seed=seed)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    _line_contract(out, spec, "extra.bi", trace=False)
+    assert "query_p50_ms" in out["metrics"]
+    r = control.readings(find_cell(root, "extra.bi"), seed, 1.5, load_module)
+    assert r["seed"] == seed and r["requests"] == out["attempted"]
+    assert r["answers_wrong_or_missing"] >= 0
+    assert r["rank_max_rel_err.float32"] < r["rank_max_rel_err.bfloat16"]
+
+
+def test_a_config_without_a_cpu_twin_fails_naming_the_file(tmp_path):
+    src = _later_checkout(tmp_path)
+    _add_cell_from_new_files(src)
+    (src / CHIP_REL / "tests" / "tiny" / "configs" / "extra-ldbc.json").unlink()
+    with pytest.raises(pytest.fail.Exception,
+                       match="add benchmarks/chip/tests/tiny/configs/extra-ldbc.json"):
+        _tiny_root(tmp_path, src)
 
 
 def test_cli_without_a_tpu_exits_nonzero_and_prints_nothing():

@@ -15,7 +15,7 @@ CHIP = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(CHIP))
 
 import trace_reduce  # noqa: E402
-from harness import SPAN_PREFIX, load_module  # noqa: E402
+from harness import SPAN_PREFIX, BenchError, load_module  # noqa: E402
 
 DATA = Path(__file__).resolve().parent / "data"
 
@@ -190,6 +190,17 @@ def test_bi_window_holds_one_pagerank_over_knows():
         names = [n for _, n, _ in driver.schedule(traffic, seed, seconds)]
         assert names.count("pagerank_knows") == 1
         assert {n for n in names} == set(traffic["mix"])
+
+
+def test_open_loop_reference_is_named_by_the_traffic():
+    driver = load_module(CHIP / "drivers" / "open_loop.py", "test_open_loop_refs")
+    bi = json.loads((CHIP / "traffic" / "bi.json").read_text())
+    assert "reference" not in bi
+    assert Path(driver.reference_module(CHIP, bi).__file__) == CHIP / "refs" / "ldbc_queries.py"
+    named = driver.reference_module(CHIP, dict(bi, reference="pagerank"))
+    assert Path(named.__file__) == CHIP / "refs" / "pagerank.py"
+    with pytest.raises(BenchError, match="no_such_ref"):
+        driver.reference_module(CHIP, dict(bi, reference="no_such_ref"))
 
 
 # -- the controls fail ------------------------------------------------------------
