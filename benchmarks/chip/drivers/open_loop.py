@@ -17,7 +17,9 @@ Check: every answer due in the window is compared with the plain reference
 once the window has closed: a template's answer exactly with
 ``refs/<reference>.py``, the module the traffic file's ``reference`` key names
 (see ``reference_module``), and ranks within the traffic file's limit of the
-float64 power iteration.
+float64 power iteration.  Inside the window each answer is cut to what the
+check needs (``Serving.kept``): the reference module decides that for its
+templates, so an answer served by any route keeps what its check compares.
 """
 
 from __future__ import annotations
@@ -34,14 +36,24 @@ import numpy as np
 LATE_AFTER_CLOSE_S = 60.0
 WARM_PASSES = 5
 DEFAULT_REFERENCE = "ldbc_queries"
+REFERENCE_API = ("reference", "compact", "program_answer", "same_answer")
 
 
 def reference_module(chip_dir: Path, traffic: dict):
     """``refs/<name>.py`` for the traffic file's ``reference`` key
-    (``DEFAULT_REFERENCE`` where it has none).  The module gives
-    ``reference(tables, dtype=np.float64)``, whose ``.answer(name, params)``
-    is a template's answer, ``program_answer(result, raw_of_dense, want)``,
-    the program's result in that form, and ``same_answer(a, b)``."""
+    (``DEFAULT_REFERENCE`` where it has none).  The module gives:
+
+    - ``reference(tables, dtype=np.float64)``, whose ``.answer(name, params)``
+      is a template's answer;
+    - ``compact(result)``, what the check needs of one answer of an installed
+      template, whichever route served it; the window keeps this until the
+      check, and nothing else of the answer;
+    - ``program_answer(kept, raw_of_dense, want)``, what ``compact`` kept, in
+      the reference's form;
+    - ``same_answer(a, b)``.
+
+    Each is required: ``start`` fails before set-up, naming any that is
+    missing."""
     from harness import load_module
 
     name = traffic.get("reference", DEFAULT_REFERENCE)
@@ -108,15 +120,6 @@ def _analytics_fns(traffic: dict) -> dict:
     return fns
 
 
-def _compact(value, installed: bool):
-    """What the check needs of an answer; the rest (frames) is dropped."""
-    if not installed:
-        return np.asarray(value)
-    return value.__class__(vset=value.vset, accumulators=value.accumulators,
-                           n_edges_scanned=value.n_edges_scanned, frames=[],
-                           alias_sets=value.alias_sets)
-
-
 def _warm(server, ctx, traffic: dict, engine) -> int:
     """Every template over all its combos, until a pass makes no lake fetch."""
     warm = [(name, p) for name in traffic["mix"]
@@ -133,11 +136,12 @@ def _warm(server, ctx, traffic: dict, engine) -> int:
     return WARM_PASSES
 
 
-def send(server, ctx, plan: list, installed: set) -> tuple[list, list]:
+def send(serving, ctx, plan: list) -> tuple[list, list]:
     """Send ``plan`` open loop; return one record per request and the
     generator's lateness per send."""
     from repro.errors import ServerOverloadedError
 
+    server = serving.server
     records: list = [None] * len(plan)
     lateness = []
     waiters = []
@@ -154,7 +158,7 @@ def send(server, ctx, plan: list, installed: set) -> tuple[list, list]:
         records[i] = {"ok": r.ok, "error": r.error,
                       "latency_s": t_done - t_due if r.ok else math.inf,
                       "queued_s": r.queued_s, "service_s": r.service_s,
-                      "answer": _compact(r.value, name in installed) if r.ok else None}
+                      "answer": serving.kept(name, r.value) if r.ok else None}
 
     for i, (at, name, params) in enumerate(plan):
         t_due = t0 + at
@@ -217,15 +221,24 @@ def _verify(records: list, plan: list, tables: dict, schema, raw_of_dense: dict,
 
 @dataclasses.dataclass
 class Serving:
-    """A started engine and server over the cell's lake, warmed."""
+    """A started engine and server over the cell's lake, warmed, and the
+    traffic's reference module."""
     tables: dict
     schema: object
     engine: object
     server: object
     installed: set
+    ref_module: object
     startup_s: float
     breakdown: dict
     warm_passes: int
+
+    def kept(self, name: str, value):
+        """What the check needs of one answer: the reference module's
+        ``compact`` of an installed template's, an analytics request's ranks."""
+        if name in self.installed:
+            return self.ref_module.compact(value)
+        return np.asarray(value)
 
     def close(self) -> None:
         try:
@@ -236,11 +249,16 @@ class Serving:
 
 def start(ctx) -> Serving:
     """Set-up: lake from the seed, a fresh engine, the server, warm-up."""
+    from harness import BenchError
     from repro.gsql.session import GraphSession
     from repro.lakehouse.objectstore import ObjectStore, StoreConfig
     from repro.serving.server import QueryServer, ServerConfig
 
     traffic, cfg = ctx.traffic, ctx.config
+    ref_module = reference_module(ctx.cell.chip_dir, traffic)
+    missing = [fn for fn in REFERENCE_API if not callable(getattr(ref_module, fn, None))]
+    if missing:
+        raise BenchError(f"{ref_module.__file__} lacks {', '.join(missing)}")
     gen = ctx.generator()
     with ctx.span("setup.generate"):
         tables = gen.generate(cfg, ctx.seed)
@@ -262,7 +280,7 @@ def start(ctx) -> Serving:
                                              batch_window_ms=srv["batch_window_ms"],
                                              max_batch_riders=srv["max_batch_riders"]))
     serving = Serving(tables, schema, engine, server, set(traffic["queries"]),
-                      startup_s, breakdown, 0)
+                      ref_module, startup_s, breakdown, 0)
     try:
         with ctx.span("setup.warm"):
             serving.warm_passes = _warm(server, ctx, traffic, engine)
@@ -281,13 +299,12 @@ def latency_ms(records: list, q: float) -> float:
 
 def run(ctx) -> dict:
     traffic = ctx.traffic
-    ref_module = reference_module(ctx.cell.chip_dir, traffic)
     s = start(ctx)
     try:
         plan = schedule(traffic, ctx.seed, ctx.seconds)
         cache0 = dict(s.engine.cache.stats)
         with ctx.window():
-            records, lateness = send(s.server, ctx, plan, s.installed)
+            records, lateness = send(s, ctx, plan)
         cache1 = dict(s.engine.cache.stats)
         ctx.read_memory()
         raw_of_dense = {vt: s.engine.read_vertex_column(
@@ -297,7 +314,7 @@ def run(ctx) -> dict:
         s.close()
 
     checks, wrong = _verify(records, plan, s.tables, s.schema, raw_of_dense, traffic,
-                            s.installed, ref_module)
+                            s.installed, s.ref_module)
     for i, name, params, why in wrong[:5]:
         ctx.log(f"request {i} {name} {params}: {why}")
     ok = [r for r in records if r and r["ok"]]

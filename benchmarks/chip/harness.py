@@ -9,9 +9,23 @@ lives in a file of its own, found by the name ``BENCHMARK.json`` gives it:
   ``drivers/<driver>.py``, which runs set-up, the window and the check;
 - ``refs/<reference>.py``: the plain reference of an open-loop traffic's
   templates, named by the traffic file's ``reference`` key (``ldbc_queries``
-  where it has none; what it gives: ``drivers/open_loop.py``,
-  ``reference_module``);
+  where it has none).  It gives ``reference``, ``compact``,
+  ``program_answer`` and ``same_answer`` (``drivers/open_loop.py``,
+  ``reference_module``); ``compact(result)`` is what the check needs of one
+  answer, whichever route served it (full engine, shared scan, or the
+  point-lookup tier), and the window keeps nothing else;
 - ``layers/<metric>.py``: one reader per per-layer metric.
+
+A reference's ``same_answer`` compares ``n_edges_scanned``, which the
+program promises bit-identical on every route (``core/lookup.py``): the
+tests' answer fault alters that field on each route an installed template
+can take, and has to see ``correct`` come out false.  The control
+(``control.py``) runs the reference with ``dtype=np.float32``.  For a
+template with no float accumulator, that ``dtype`` applies to what the
+template orders or compares by, so that the control still reads above the
+exact limit of 0: ``creationDate`` values over 2**24 held as float32 merge
+ties and move ``>`` and ``<=`` boundaries, which reorders a top-k or lets
+one more row through a date filter.
 
 A driver reports raw observations; this module picks the cell's end-to-end
 metrics (``--trace 0``) or per-layer metrics (``--trace 1``) from them and

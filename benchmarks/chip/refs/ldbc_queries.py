@@ -141,8 +141,16 @@ def reference(tables: dict, dtype=np.float64) -> LDBCReference:
     return LDBCReference(tables, dtype)
 
 
+def compact(result):
+    """What the check needs of one answer, kept from the window until the
+    check: the ``QueryResult`` without its frames."""
+    return result.__class__(vset=result.vset, accumulators=result.accumulators,
+                            n_edges_scanned=result.n_edges_scanned, frames=[],
+                            alias_sets=result.alias_sets)
+
+
 def program_answer(result, raw_of_dense: dict, ref: dict) -> dict:
-    """The program's ``QueryResult`` in the reference's raw-id form; the
+    """An answer as ``compact`` kept it, in the reference's raw-id form; the
     vertex type of each accumulator is the reference's for that name."""
     def as_set(vs):
         return vs.vertex_type, np.sort(raw_of_dense[vs.vertex_type][vs.ids()])

@@ -63,7 +63,7 @@ def main(argv=None) -> int:
             traffic = dict(cell.traffic, rate_per_s=rate)
             plan = driver.schedule(traffic, args.seed, sweep["seconds"])
             t0 = time.perf_counter()
-            records, lateness = driver.send(serving.server, ctx, plan, serving.installed)
+            records, lateness = driver.send(serving, ctx, plan)
             wall = time.perf_counter() - t0
             p50, p95 = driver.latency_ms(records, 50), driver.latency_ms(records, 95)
             ok = sum(1 for r in records if r and r["ok"])

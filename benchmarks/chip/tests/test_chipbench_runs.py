@@ -27,6 +27,7 @@ import pytest
 CHIP = Path(__file__).resolve().parents[1]
 REPO = CHIP.parents[1]
 TINY = Path(__file__).resolve().parent / "tiny"
+DATA = Path(__file__).resolve().parent / "data"
 sys.path.insert(0, str(CHIP))
 
 import control  # noqa: E402
@@ -134,8 +135,10 @@ def test_traced_run_reports_per_layer_metrics(root, workload):
     assert program_read & _applies(spec, "per_layer", workload) <= set(out["metrics"])
 
 
-@pytest.mark.parametrize("workload", OPEN_LOOP_CELLS)
-def test_answer_altered_is_not_correct(root, monkeypatch, workload):
+def _alter_answers(monkeypatch) -> None:
+    """Every answer of an installed template one edge off where it is made, on
+    each route the session module sends it: the full engine, the shared scan
+    and the point-lookup tier."""
     import repro.gsql.session as session
 
     def altered(fn):
@@ -146,9 +149,13 @@ def test_answer_altered_is_not_correct(root, monkeypatch, workload):
             return res
         return run
 
-    monkeypatch.setattr(session, "execute_compiled", altered(session.execute_compiled))
-    monkeypatch.setattr(session, "execute_compiled_batch",
-                        altered(session.execute_compiled_batch))
+    for name in ("execute_compiled", "execute_compiled_batch", "execute_lookup"):
+        monkeypatch.setattr(session, name, altered(getattr(session, name)))
+
+
+@pytest.mark.parametrize("workload", OPEN_LOOP_CELLS)
+def test_answer_altered_is_not_correct(root, monkeypatch, workload):
+    _alter_answers(monkeypatch)
     out = _run(root, workload, seed=5)
     assert not out["correct"]
     assert out["checks"]["answers_wrong_or_missing"]["value"] > 0
@@ -185,26 +192,34 @@ def test_ranks_altered_is_not_correct(root, monkeypatch, workload):
 
 # -- a cell from new files alone ---------------------------------------------------
 
-def _add_cell_from_new_files(src: Path) -> None:
-    """What a later change adds for a cell: a configuration, its CPU twin and
-    a traffic mix as new files, entries appended to ``BENCHMARK.json``, and
-    the cell's name appended to the lists of the metrics it reports."""
+def _add_cell_from_new_files(src: Path, cell: str = "extra.bi",
+                             traffic: str = "extra-bi", refs: tuple = ()) -> None:
+    """What a later change adds for a cell: a configuration (a copy of the
+    LDBC one), its CPU twin, a traffic mix and the ``refs`` modules it names
+    as new files, entries appended to ``BENCHMARK.json``, and the cell's name
+    appended to the lists of the metrics it reports.  ``extra-bi`` is the BI
+    mix; any other ``traffic`` is ``tests/data/<traffic>.json``."""
     chip = src / CHIP_REL
     shutil.copy(chip / "configs" / "ldbc-snb-sf1.json", chip / "configs" / "extra-ldbc.json")
     shutil.copy(chip / "tests" / "tiny" / "configs" / "ldbc-snb-sf1.json",
                 chip / "tests" / "tiny" / "configs" / "extra-ldbc.json")
-    traffic = json.loads((chip / "traffic" / "bi.json").read_text())
-    (chip / "traffic" / "extra-bi.json").write_text(
-        json.dumps(dict(traffic, reference="ldbc_queries")))
+    if traffic == "extra-bi":
+        body = dict(json.loads((chip / "traffic" / "bi.json").read_text()),
+                    reference="ldbc_queries")
+    else:
+        body = json.loads((DATA / f"{traffic}.json").read_text())
+    (chip / "traffic" / f"{traffic}.json").write_text(json.dumps(body))
+    for name in refs:
+        shutil.copy(DATA / f"{name}.py", chip / "refs" / f"{name}.py")
     spec = json.loads((src / "BENCHMARK.json").read_text())
     spec["configs"].append({"name": "extra-ldbc", "source": "test",
                             "file": "benchmarks/chip/configs/extra-ldbc.json",
                             "reduced": [], "why": "test"})
-    spec["workloads"].append({"name": "extra.bi", "config": "extra-ldbc",
-                              "traffic": "extra-bi", "chips": 1, "why": "test"})
+    spec["workloads"].append({"name": cell, "config": "extra-ldbc",
+                              "traffic": traffic, "chips": 1, "why": "test"})
     for m in spec["end_to_end"] + spec["per_layer"]:
         if m["name"] in ("query_p50_ms", "queue_wait_ms"):
-            m["workloads"].append("extra.bi")
+            m["workloads"].append(cell)
     (src / "BENCHMARK.json").write_text(json.dumps(spec, indent=2))
 
 
@@ -231,15 +246,20 @@ def _later_checkout(tmp: Path) -> Path:
     return src
 
 
+def _assert_only_added(before: dict, after: dict) -> None:
+    """No file of ``before`` changed, but for entries appended to
+    ``BENCHMARK.json``."""
+    spec_rel = Path("BENCHMARK.json")
+    assert all(after[rel] == data for rel, data in before.items() if rel != spec_rel)
+    assert _only_appended(json.loads(before[spec_rel]), json.loads(after[spec_rel]))
+
+
 def test_a_cell_from_new_files_alone_runs_correct_with_its_control(tmp_path):
     src = _later_checkout(tmp_path)
     before = _files(src)
     _add_cell_from_new_files(src)
     root = _tiny_root(tmp_path, src)
-    after = _files(src)
-    spec_rel = Path("BENCHMARK.json")
-    assert all(after[rel] == data for rel, data in before.items() if rel != spec_rel)
-    assert _only_appended(json.loads(before[spec_rel]), json.loads(after[spec_rel]))
+    _assert_only_added(before, _files(src))
 
     spec = json.loads((root / "BENCHMARK.json").read_text())
     seed = 2**31 + 23
@@ -252,6 +272,32 @@ def test_a_cell_from_new_files_alone_runs_correct_with_its_control(tmp_path):
     assert r["seed"] == seed and r["requests"] == out["attempted"]
     assert r["answers_wrong_or_missing"] >= 0
     assert r["rank_max_rel_err.float32"] < r["rank_max_rel_err.bfloat16"]
+
+
+def test_a_lookup_routed_cell_from_new_files_alone_is_checked_on_its_route(
+        tmp_path, monkeypatch):
+    """A cell whose templates the server sends through the point-lookup tier:
+    its reference's ``compact`` keeps what ``ldbc_queries.compact`` drops (the
+    route), every answer reaches the check from that tier, and an answer
+    altered there is caught."""
+    src = _later_checkout(tmp_path)
+    before = _files(src)
+    _add_cell_from_new_files(src, "extra.point", "extra-point", refs=("point_ref",))
+    root = _tiny_root(tmp_path, src)
+    _assert_only_added(before, _files(src))
+
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    out = _run(root, "extra.point", seed=2**31 + 29)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    _line_contract(out, spec, "extra.point", trace=False)
+    routes = sys.modules["chipbench_ref_point_ref"].ROUTES
+    assert routes == ["lookup"] * out["attempted"]
+
+    _alter_answers(monkeypatch)
+    out = _run(root, "extra.point", seed=2**31 + 31)
+    assert not out["correct"]
+    assert out["checks"]["answers_wrong_or_missing"]["value"] > 0
 
 
 def test_a_config_without_a_cpu_twin_fails_naming_the_file(tmp_path):

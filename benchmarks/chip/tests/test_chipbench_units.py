@@ -203,6 +203,55 @@ def test_open_loop_reference_is_named_by_the_traffic():
         driver.reference_module(CHIP, dict(bi, reference="no_such_ref"))
 
 
+def test_ldbc_compact_is_the_answer_without_its_frames(tmp_path):
+    """``ldbc_queries.compact`` keeps, field for field, what the open-loop
+    driver kept of an installed template's answer before the reference module
+    decided it (the driver's former body, below)."""
+    import dataclasses
+
+    from refs.ldbc_queries import compact
+    from repro.core.engine import GraphLakeEngine
+    from repro.gsql.session import GraphSession
+    from repro.lakehouse.objectstore import ObjectStore, StoreConfig
+
+    gen = _gen("ldbc")
+    store = ObjectStore(StoreConfig(root=str(tmp_path / "lake")))
+    gen.write(gen.generate(LDBC_TINY, 4), store, LDBC_TINY)
+    engine = GraphLakeEngine(store, gen.graph_schema())
+    try:
+        engine.startup()
+        session = GraphSession.for_engine(engine)
+        session.install("bi1", json.loads((CHIP / "traffic" / "bi.json").read_text())
+                        ["queries"]["bi1"])
+        value = session.query("bi1", tag="Music", date=20090101)
+    finally:
+        engine.close()
+    assert value.frames and value.n_edges_scanned > 0
+    want = value.__class__(vset=value.vset, accumulators=value.accumulators,
+                           n_edges_scanned=value.n_edges_scanned, frames=[],
+                           alias_sets=value.alias_sets)
+    got = compact(value)
+    assert type(got) is type(want)
+    for f in dataclasses.fields(want):
+        a, b = getattr(got, f.name), getattr(want, f.name)
+        assert a is b or a == b, f.name
+
+
+def test_serving_refuses_a_reference_without_compact(tmp_path):
+    from types import SimpleNamespace
+
+    driver = load_module(CHIP / "drivers" / "open_loop.py", "test_open_loop_api")
+    (tmp_path / "refs").mkdir()
+    (tmp_path / "refs" / "no_compact.py").write_text(
+        "def reference(tables, dtype=None): pass\n"
+        "def program_answer(kept, raw_of_dense, want): pass\n"
+        "def same_answer(a, b): pass\n")
+    ctx = SimpleNamespace(cell=SimpleNamespace(chip_dir=tmp_path), config={},
+                          traffic={"reference": "no_compact"})
+    with pytest.raises(BenchError, match="lacks compact$"):
+        driver.start(ctx)
+
+
 # -- the controls fail ------------------------------------------------------------
 
 def test_float32_accumulators_change_a_bi_answer():
